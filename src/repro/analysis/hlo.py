@@ -1,4 +1,5 @@
-"""Parse collective ops out of compiled HLO text.
+"""Parse collective ops, and the VMEM of Mosaic kernels, out of compiled HLO
+text.
 
 ``cost_analysis()`` does not expose collective bytes, so we regex the
 post-SPMD module: every all-reduce / all-gather / reduce-scatter / all-to-all
@@ -31,6 +32,9 @@ _COMP_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\([^)]*\))?\s*->.*\{\
 _OP_RE = re.compile(r"=\s*(\([^=]*?\)|\S+)\s+(" + "|".join(COLLECTIVES) + r")(-(start|done))?\(")
 _EDGE_RE = re.compile(r"(?:calls|body|condition|to_apply|branch_computations)=\{?%?([\w.\-]+(?:,\s*%?[\w.\-]+)*)\}?")
 _BODY_RE = re.compile(r"\bbody=%?([\w.\-]+)")
+_SCOPED_VMEM_RE = re.compile(
+    r'"used_scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"'
+)
 
 
 def _bytes_of_type(tstr: str) -> int:
@@ -115,3 +119,14 @@ def parse_collectives(hlo_text: str, *, body_trip_counts: dict | None = None) ->
         stats.per_op_count[op] += mult
         stats.total_bytes += nbytes * mult
     return stats
+
+
+def kernel_scoped_vmem(hlo_text: str) -> list[int]:
+    """Bytes of VMEM the TPU compiler scoped for each Mosaic kernel call
+    (``tpu_custom_call``) of a compiled module, in program order."""
+    return [
+        int(size)
+        for line in hlo_text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        for size in _SCOPED_VMEM_RE.findall(line)
+    ]

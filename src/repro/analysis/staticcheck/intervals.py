@@ -323,9 +323,9 @@ class _Interp:
         env: dict = {}
 
         def read(atom) -> AbsVal:
-            import jax
+            from jax.extend.core import Literal
 
-            if isinstance(atom, jax.core.Literal):
+            if isinstance(atom, Literal):
                 v = int(np.asarray(atom.val).min())
                 hi = int(np.asarray(atom.val).max())
                 return AbsVal(IV(v, hi))

@@ -22,12 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # scratch memory space: TPU backend name moved across versions
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -113,13 +108,11 @@ def flash_attention_bhsd(
         scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, n_k=n_k,
     )
-    scratch = []
-    if _VMEM is not None:
-        scratch = [
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, dh), jnp.float32),
-        ]
+    scratch = [
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, dh), jnp.float32),
+    ]
     grid = (bhq, n_q, n_k)
     return pl.pallas_call(
         kernel,

@@ -24,12 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 CAP = 30.0
 
@@ -90,7 +85,7 @@ def wkv6_bhsn(
     n_chunks = s // chunk
     u3 = u[:, None, :]
     kernel = functools.partial(_wkv_kernel, chunk=chunk, n=n)
-    scratch = [] if _VMEM is None else [_VMEM((n, n), jnp.float32)]
+    scratch = [pltpu.VMEM((n, n), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=(bh, n_chunks),

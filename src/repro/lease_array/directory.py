@@ -50,7 +50,7 @@ class LeaseArrayDirectory:
         lease_ticks: int = 6,
         renew_margin: int | None = None,
         max_workers: int = 32,
-        backend: str = "jnp",
+        backend: str | None = None,
         max_delay_ticks: int = 0,
     ) -> None:
         self.n_shards = n_shards

@@ -49,7 +49,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from .netplane import NetPlaneState, init_netplane
-from .ops import _margin_scan_impl, _window_scan_impl, lease_plane_tick
+from .ops import (
+    _margin_scan_impl,
+    _window_scan_impl,
+    lease_plane_tick,
+    resolve_backend,
+)
 from .ref import owner_row
 from .scenario import (
     CORRUPTION_PLANES,
@@ -71,11 +76,6 @@ from .state import (
     lease_quarters,
     rate1_clock,
 )
-
-
-#: flips True after the first analyzer failure so a broken static checker
-#: warns once instead of blocking (or spamming) every dispatch
-_STATIC_CHECK_FAILED = False
 
 _DEPRECATED_STEP_KWARGS = (
     "per-plane LeaseArrayEngine.step arguments (attempt=, release=, "
@@ -254,16 +254,51 @@ def _trace_fn(
         )
 
     if n_devices > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh
 
         mesh = Mesh(np.array(jax.devices()[:n_devices]), ("cells",))
         in_specs, out_specs = _cell_sharding_specs(planes_keys)
-        run = shard_map(
+        sharded = jax.shard_map(
             run, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
+
+        def run(state, net, t0, clk0, rst0, planes):
+            # an uneven split pads the cell axis with empty cells (the
+            # same sentinels init_state/init_netplane and the plane
+            # registry use), so every device gets an equal share
+            n = state.n_cells
+            state, net, planes = _pad_cell_axis(state, net, planes, n_devices)
+            out = sharded(state, net, t0, clk0, rst0, planes)
+            if state.n_cells == n:
+                return out
+            return jax.tree.map(lambda a: a[..., :n], out)
+
     return jax.jit(run)
+
+
+def _pad_cell_axis(state, net, planes: dict, multiple: int):
+    """Append empty cells until the cell axis divides by ``multiple``:
+    fresh ``init_state``/``init_netplane`` columns and each cell plane's
+    registered default (no attempt, release or extend)."""
+    pad = (-state.n_cells) % multiple
+    if pad == 0:
+        return state, net, planes
+    A, P = state.n_acceptors, state.n_proposers
+    cat = lambda a, b: jnp.concatenate([a, b], axis=-1)
+    state = jax.tree.map(cat, state, init_state(pad, A, P))
+    net = jax.tree.map(cat, net, init_netplane(pad, A))
+    planes = {
+        k: (
+            jnp.pad(
+                v, [(0, 0)] * (v.ndim - 1) + [(0, pad)],
+                constant_values=PLANES[k].default,
+            )
+            if "N" in PLANES[k].dims else v
+        )
+        for k, v in planes.items()
+    }
+    return state, net, planes
 
 
 @functools.lru_cache(maxsize=None)
@@ -313,18 +348,36 @@ def _sweep_fn(
 
     batched = jax.vmap(one, in_axes=(None, None, None, None, None, 0, 0))
     if n_devices > 1:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
 
-        mesh = Mesh(np.array(jax.devices()[:n_devices]), ("b",))
-        batched = shard_map(
-            batched, mesh=mesh,
+        batched = jax.shard_map(
+            batched, mesh=_batch_mesh(n_devices),
             in_specs=(P(), P(), P(), P(), P(), P("b"), P("b")),
             out_specs=P("b"),
-            check_rep=False,
+            check_vma=False,
         )
     donate = (5,) if collect == "owners" else ()
     return jax.jit(batched, donate_argnums=donate)
+
+
+def _batch_mesh(n_devices: int):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:n_devices]), ("b",))
+
+
+def _shard_batch(plane, n_devices: int):
+    """A stacked [B, ...] sweep plane split over ``n_devices`` on upload:
+    padded on the host to a device multiple with copies of scenario 0
+    (their results are sliced off), then each device receives only its
+    share. The buffer is always fresh, so donating it is safe."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    v = np.asarray(plane)
+    pad = (-v.shape[0]) % n_devices
+    if pad:
+        v = np.concatenate([v, np.repeat(v[:1], pad, axis=0)])
+    return jax.device_put(v, NamedSharding(_batch_mesh(n_devices), P("b")))
 
 
 class LeaseArrayEngine:
@@ -337,7 +390,7 @@ class LeaseArrayEngine:
         lease_ticks: int = 3,
         round_ticks: int = 1,
         drift_eps: float = 0.0,
-        backend: str = "jnp",
+        backend: str | None = None,
         window: int = 16,
         restart_guard: bool = True,
         skip_stable: bool = True,
@@ -357,7 +410,8 @@ class LeaseArrayEngine:
         #: outlives a fast acceptor's timer. ε=0 = the exact rate-1 engine.
         self.drift_eps = float(drift_eps)
         self.guard_q4 = guarded_lease_q4(self.lease_q4, self.drift_eps)
-        self.backend = backend
+        #: the platform's choice unless named (``ops.resolve_backend``)
+        self.backend = resolve_backend(backend)
         self.window = int(window)
         self.state = init_state(n_cells, n_acceptors, n_proposers)
         self.net: NetPlaneState = init_netplane(n_cells, n_acceptors)
@@ -435,10 +489,9 @@ class LeaseArrayEngine:
         dispatch. Complements ``_check_pack_budget``: the hand bound is
         skipped under tracing and blind to everything but ballots and
         lease deadlines, while this proves every traced-core intermediate
-        stays in int32. Best-effort by design — an analyzer import/bug
-        failure warns once and never blocks a dispatch; a *finding*
-        (an actual overflow proof) raises."""
-        global _STATIC_CHECK_FAILED
+        stays in int32. Fails closed: a finding (an actual overflow proof)
+        raises, and so does the analyzer itself crashing — a dispatch never
+        runs with the proof silently off."""
         max_rate = max(int(max_rate), QUARTERS)
         clk_max = int(max(self.prop_clk.max(), self.acc_clk.max(), 0))
         try:
@@ -450,14 +503,10 @@ class LeaseArrayEngine:
                 int(max_restarts),
             )
         except Exception as e:
-            if not _STATIC_CHECK_FAILED:
-                _STATIC_CHECK_FAILED = True
-                warnings.warn(
-                    f"static pack-budget analysis unavailable "
-                    f"(falling back to the hand check only): {e!r}",
-                    RuntimeWarning, stacklevel=3,
-                )
-            return
+            raise RuntimeError(
+                f"static pack-budget analysis failed, so the {t_end}-tick "
+                f"replay is refused: {e!r}"
+            ) from e
         if findings:
             raise ValueError(
                 f"static analysis refused a {t_end}-tick replay — the "
@@ -719,12 +768,10 @@ class LeaseArrayEngine:
                 and (np.asarray(v) == PLANES[k].default).all()
             )
         }
-        n_dev = len(jax.devices())
-        if n_dev > 1 and self.n_cells % n_dev != 0:
-            n_dev = 1  # uneven cell split: stay on one device
         fn = _trace_fn(
             self.majority, self.lease_q4, self.round_q4, self.guard_q4,
-            self.backend, sync, 512, self.window, n_dev, tuple(planes),
+            self.backend, sync, 512, self.window, len(jax.devices()),
+            tuple(planes),
             self.restart_guard, self.skip_stable,
         )
         self.state, self.net, owners, counts = fn(
@@ -758,7 +805,8 @@ class LeaseArrayEngine:
         stacked planes are donated — their buffers become the output cubes);
         with more than one JAX device visible it is additionally
         ``shard_map``-ed across a 1-D device mesh over the batch axis
-        (B must then divide by the device count).
+        (the planes are padded to a device multiple of B on the host, and
+        each device is sent only its share).
 
         ``collect="summary"`` (default) reduces inside the dispatch — only
         [B]-shaped verdicts and the [B, N] final owner rows come back, so
@@ -842,18 +890,20 @@ class LeaseArrayEngine:
         # output cubes); copy those leaves when they are already device
         # arrays so a caller can reuse its stacked Scenario
         donating = collect == "owners"
+        n_dev = len(jax.devices())
         cell_planes, rest_planes = {}, {}
         for k, v in stacked.planes.items():
             if k in drop_keys:
                 continue
-            arr = jnp.asarray(v)
-            if k in ("attempts", "releases"):
-                cell_planes[k] = (
-                    arr.copy() if donating and arr is v else arr
-                )
+            cell = k in ("attempts", "releases")
+            if n_dev > 1:
+                arr = _shard_batch(v, n_dev)
             else:
-                rest_planes[k] = arr
-        B, T = cell_planes["attempts"].shape[:2]
+                arr = jnp.asarray(v)
+                if donating and cell and arr is v:
+                    arr = arr.copy()
+            (cell_planes if cell else rest_planes)[k] = arr
+        B, T = np.shape(stacked.planes["attempts"])[:2]
         if T == 0:
             raise ValueError("sweep scenarios must have at least one tick")
         # a sweep is read-only: pick the model without flipping the engine
@@ -866,30 +916,25 @@ class LeaseArrayEngine:
         mr = self._max_restarts(stacked.planes.get("prop_restart"))
         self._check_pack_budget(self.t + T, dmax, rmax, mr)
         self._static_bound_check(self.t + T, dmax, rmax, mr)
-        n_dev = len(jax.devices())
-        if n_dev > 1 and B % n_dev != 0:
-            n_dev = 1  # uneven batch: fall back to single-device vmap
         fn = _sweep_fn(
             self.majority, self.lease_q4, self.round_q4, self.guard_q4,
-            backend or self.backend, sync, 512, self.window, collect, n_dev,
+            self.backend if backend is None else resolve_backend(backend),
+            sync, 512, self.window, collect, n_dev,
             self.restart_guard, self.skip_stable,
         )
         out = fn(
             self.state, self.net, jnp.int32(self.t), self._clk0(),
             self._rst0(), cell_planes, rest_planes,
         )
+        host = lambda a: np.asarray(a)[:B]  # drop the padding scenarios
         result = SweepResult(
-            max_owner_count=np.asarray(out["max_owner_count"]),
-            owned_frac=np.asarray(out["owned_frac"]),
-            final_owners=np.asarray(out["final_owners"]),
-            owners=(
-                np.asarray(out["owners"]) if collect == "owners" else None
-            ),
-            counts=(
-                np.asarray(out["counts"]) if collect == "owners" else None
-            ),
+            max_owner_count=host(out["max_owner_count"]),
+            owned_frac=host(out["owned_frac"]),
+            final_owners=host(out["final_owners"]),
+            owners=host(out["owners"]) if collect == "owners" else None,
+            counts=host(out["counts"]) if collect == "owners" else None,
             margins=(
-                {k: np.asarray(v) for k, v in out["margins"].items()}
+                {k: host(v) for k, v in out["margins"].items()}
                 if collect == "margins" else None
             ),
         )

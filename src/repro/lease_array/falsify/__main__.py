@@ -24,6 +24,7 @@ from pathlib import Path
 
 import numpy as np
 
+from ...compile_cache import enable_compile_cache
 from ..scenario import PLANES, plane_digest
 from .search import FalsifyConfig, search
 from .shrink import shrink
@@ -57,7 +58,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--pop", type=int, default=256)
     ap.add_argument("--generations", type=int, default=8)
-    ap.add_argument("--backend", default="jnp")
+    ap.add_argument(
+        "--backend", default=None,
+        help="lease-plane backend (default: the compiled kernel on a TPU, "
+             "the jnp scan elsewhere)",
+    )
     ap.add_argument(
         "--expect", choices=("violation", "none"), default=None,
         help="exit nonzero unless the run ends this way (the CI contract)",
@@ -71,6 +76,7 @@ def main(argv=None) -> int:
         help="sweep probes the survivor shrinker may spend (0 = skip)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = FalsifyConfig(
         seed=args.seed, pop_size=args.pop, generations=args.generations,

@@ -67,7 +67,7 @@ class FalsifyConfig:
     lease_ticks: int = 2
     round_ticks: int = 3
     drift_eps: float = 0.25
-    backend: str = "jnp"
+    backend: Optional[str] = None  # None: the platform's choice
     # population / budget
     seed: int = 0
     pop_size: int = 256

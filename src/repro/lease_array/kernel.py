@@ -50,14 +50,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU backend name moved across versions (same guard as flash_attention)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .netplane import NetPlaneState, delayed_tick_math, legs_select
 from .ref import sync_tick_math
@@ -75,13 +68,6 @@ _OWN_LEASE = PackedLeaseState._fields.index("owner_lease")
 _LEASE_ROWS = (None, None, 1, 1)  # None -> the plane keeps its A rows
 # NetPlaneState: 6 [A, bn] slot planes then 6 [1, bn] round rows
 _NET_ROWS = (None,) * 6 + (1,) * 6
-
-
-def _scalar_spec(n: int):
-    """Spec for the [n] int32 scan-scalar vector (SMEM on real TPUs)."""
-    if _SMEM is not None:
-        return pl.BlockSpec(memory_space=_SMEM)
-    return pl.BlockSpec((n,), lambda i, w: (0,))
 
 
 def _state_specs(rows, n_acceptors: int, block_n: int):
@@ -118,7 +104,7 @@ class LaunchPlan(NamedTuple):
     launch to drift out of sync.
 
     ``in_shapes``/``out_shapes`` align 1:1 with ``in_specs``/``out_specs``.
-    The leading scalar-vector input rides in SMEM on real TPUs; its spec has
+    The leading scalar-vector input rides in SMEM; its spec has
     no block shape, which the checker treats as exempt from tiling rules.
     """
 
@@ -159,7 +145,10 @@ def _launch_plan(
     cell_spec = _cell_plane_spec(tw, 1, block_n)
     cell_shape = (n_windows, tw, 1, N)
     in_specs = (
-        (_scalar_spec(2), *state_specs, *(cell_spec,) * n_cell_planes)
+        (
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # [2] scan scalars, whole
+            *state_specs, *(cell_spec,) * n_cell_planes,
+        )
         + tuple(_bcast_plane_spec(tw, r, c) for r, c in bcast_rows)
     )
     in_shapes = (
@@ -578,3 +567,29 @@ def lease_window_delayed_pallas(
     owners = outs[n_state].reshape(n_windows * tw, N)[:T]
     counts = outs[n_state + 1].reshape(n_windows * tw, N)[:T]
     return new_packed, new_net, owners, counts
+
+
+def delayed_kernel_args(
+    n_acceptors: int, n_cells: int, n_proposers: int, n_ticks: int, *,
+    extend: bool = False, restart: bool = False, sharding=None,
+) -> tuple[tuple, dict]:
+    """Abstract int32 arguments of :func:`lease_window_delayed_pallas` for
+    an ahead-of-time compile without data: ``(args, streams)``, the
+    positional state and planes and the keyword streams of the ``extends``
+    and restart variants, as ``jax.jit(f).lower(args, streams)`` takes
+    them. ``sharding`` places every argument (e.g. on a described chip)."""
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
+    A, N, P, T = n_acceptors, n_cells, n_proposers, n_ticks
+    args = (
+        PackedLeaseState(sds(A, N), sds(A, N), sds(1, N), sds(1, N)),
+        NetPlaneState(*([sds(A, N)] * 6 + [sds(1, N)] * 6)),
+        sds(), sds(T, N), sds(T, N), sds(T, A), sds(T, P), sds(T, A),
+        sds(T, P, A),
+    )
+    streams = {"extends": sds(T, N)} if extend else {}
+    if restart:
+        streams.update(
+            acc_restart=sds(T, A), acc_deaf=sds(T, A),
+            prop_restart=sds(T, P), prop_rc=sds(T, P),
+        )
+    return args, streams

@@ -9,11 +9,15 @@ ONE dispatch. All backends run the identical packed tick math
 bit-for-bit:
 
   - ``"jnp"``        — `lax.scan` over the packed planes (the XLA-lowered
-                       fallback; also the oracle every kernel is tested
-                       against);
+                       path off the TPU; also the oracle every kernel is
+                       tested against);
   - ``"pallas"``     — the time-resident window kernel, interpret mode
-                       (runs anywhere; correctness CI);
-  - ``"pallas_tpu"`` — the same kernel compiled for real TPUs.
+                       (CPU only; correctness CI);
+  - ``"pallas_tpu"`` — the same kernel compiled for the TPU.
+
+``backend=None`` (every entry point's default) lets the platform choose
+(:func:`resolve_backend`): the compiled kernel on a TPU, the jnp scan
+anywhere else.
 
 One step: :func:`lease_plane_tick` advances every cell one tick of either
 network model — the synchronous zero-delay tick (``sync=True``) or the
@@ -66,6 +70,30 @@ from .state import (
 )
 
 BACKENDS = ("jnp", "pallas", "pallas_tpu")
+
+
+def resolve_backend(backend: str | None = None) -> str:
+    """The lease-plane backend for this process's platform. ``None`` picks
+    the compiled kernel (``"pallas_tpu"``) on a TPU and the jnp scan
+    anywhere else. A named backend must suit the platform: the interpret-
+    mode kernel on a TPU, or the compiled kernel off one, raises instead of
+    running something other than what was asked for."""
+    on_tpu = jax.default_backend() == "tpu"
+    if backend is None:
+        return "pallas_tpu" if on_tpu else "jnp"
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown lease-plane backend {backend!r}")
+    if backend == "pallas" and on_tpu:
+        raise ValueError(
+            "backend='pallas' is the interpret-mode kernel; on a TPU use "
+            "'pallas_tpu' (or leave backend unset)"
+        )
+    if backend == "pallas_tpu" and not on_tpu:
+        raise ValueError(
+            f"backend='pallas_tpu' needs a TPU; JAX's default backend is "
+            f"{jax.default_backend()!r}"
+        )
+    return backend
 
 
 def _local_clock_planes(t0, T: int, clk0, planes: dict, n_proposers: int,
@@ -202,8 +230,7 @@ def _window_scan_impl(
     ``t0`` (None = the rate-1 reading ``4·t0``); ``rst0`` the
     (restart-counter [P], deaf-until [A]) restart history at ``t0``
     (None = fresh). Returns (state', net', owners [T, N], counts [T, N])."""
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown lease-plane backend {backend!r}")
+    backend = resolve_backend(backend)
     P = state.n_proposers
     A, N = state.highest_promised.shape
     t0 = jnp.asarray(t0, jnp.int32)
@@ -698,7 +725,7 @@ def lease_window_scan(
     clk0=None,
     rst0=None,
     restart_guard: bool = True,
-    backend: str = "jnp",
+    backend: str | None = None,
     sync: bool = False,
     block_n: int = 512,
     window: int = 16,
@@ -752,7 +779,7 @@ def lease_plane_tick(
     clk0=None,
     rst0=None,
     restart_guard: bool = True,
-    backend: str = "jnp",
+    backend: str | None = None,
     block_n: int = 512,
     sync: bool = False,
     window: int = 16,
@@ -768,8 +795,9 @@ def lease_plane_tick(
     ``prop_rate``/``acc_rate`` planes advance the clocks *after* this
     tick's deadlines are evaluated, so a stateful caller carries
     ``clk0 + rate`` into the next tick (``engine.step`` does). backend:
-    "jnp" (reference), "pallas" (kernel, interpret mode — runs anywhere),
-    "pallas_tpu" (compiled kernel, real TPUs). Returns
+    None (the platform's choice, :func:`resolve_backend`), "jnp"
+    (reference), "pallas" (kernel, interpret mode — CPU only) or
+    "pallas_tpu" (compiled kernel, TPU only). Returns
     (new_state, new_net, owner_count[N]) — owner_count is the per-cell
     number of proposers who believe they own it (>1 would be a §4
     violation).

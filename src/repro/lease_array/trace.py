@@ -484,7 +484,8 @@ def trace_from_scenario(
 
 
 def replay_array(
-    trace: Trace, *, backend: str = "jnp", netplane: Optional[bool] = None,
+    trace: Trace, *, backend: Optional[str] = None,
+    netplane: Optional[bool] = None,
     restart_guard: bool = True,
 ):
     """Owners [T, N] + per-tick owner counts via the vectorized plane.

@@ -91,7 +91,9 @@ def moe_dispatch(cfg: ModelConfig, params, x: jax.Array, *, group_size: int = 51
     dispatch = jnp.zeros((g, tg, moe.n_experts, cap), jnp.float32)
     combine = jnp.zeros((g, tg, moe.n_experts, cap), jnp.float32)
     for kk in range(moe.top_k):  # k is small (<=8); unrolled outer products
-        oc = jax.nn.one_hot(pos_tok[:, :, kk], cap, dtype=jnp.float32)
+        oc = jax.nn.one_hot(
+            pos_tok[:, :, kk].astype(jnp.int32), cap, dtype=jnp.float32
+        )
         oc = oc * keep[:, :, kk, None]
         ec = onehot_e[:, :, kk, :, None] * oc[:, :, None, :]  # (g,tg,e,cap)
         dispatch = dispatch + ec

@@ -147,6 +147,29 @@ def test_row_rejects_below_sentinel():
 
 
 # -------------------------------------------------- kernel vs oracle, width
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_backend_follows_platform(monkeypatch, platform):
+    """No backend named: the compiled kernel on a TPU, the jnp scan
+    elsewhere. A kernel mode that does not suit the platform raises."""
+    from repro.lease_array.ops import resolve_backend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    kernel, wrong = (
+        ("pallas_tpu", "pallas") if platform == "tpu"
+        else ("pallas", "pallas_tpu")
+    )
+    assert resolve_backend() == ("jnp", "pallas_tpu")[platform == "tpu"]
+    assert eng(backend=None).backend == resolve_backend()
+    assert resolve_backend(kernel) == kernel
+    assert resolve_backend("jnp") == "jnp"
+    with pytest.raises(ValueError, match=wrong):
+        resolve_backend(wrong)
+    with pytest.raises(ValueError, match=wrong):
+        eng(backend=wrong)
+    with pytest.raises(ValueError, match="unknown"):
+        resolve_backend("cuda")
+
+
 @pytest.mark.parametrize("n_cells", [64, 100, 1000])
 def test_pallas_matches_jnp_oracle(n_cells):
     tr = random_trace(

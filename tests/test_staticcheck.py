@@ -184,24 +184,23 @@ def test_traced_pack_budget_skip_warns_once(monkeypatch):
 
 
 def test_engine_static_check_failure_degrades_to_warning(monkeypatch):
-    """If the analyzer itself crashes the engine must warn once and fall
-    back to the hand check — never block a replay on a lint bug."""
+    """If the analyzer itself crashes, the engine fails closed: run_trace
+    and sweep refuse the dispatch (no warning-and-carry-on), on every
+    call, and the engine does not advance."""
     import repro.lease_array.engine as engine_mod
 
     def boom(*a, **k):
         raise RuntimeError("analyzer exploded")
 
     monkeypatch.setattr(engine_mod, "_static_pack_findings", boom)
-    monkeypatch.setattr(engine_mod, "_STATIC_CHECK_FAILED", False)
     eng = LeaseArrayEngine(4, n_acceptors=3, n_proposers=2)
     sc = Scenario.build(5, n_cells=4, n_acceptors=3, n_proposers=2)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        eng.run_trace(sc)
-        eng.run_trace(sc)  # warn once, not per call
-    msgs = [x for x in w if "static pack-budget analysis unavailable"
-            in str(x.message)]
-    assert len(msgs) == 1
+    for _ in range(2):  # refused every time, not only the first
+        with pytest.raises(RuntimeError, match="analyzer exploded"):
+            eng.run_trace(sc)
+    with pytest.raises(RuntimeError, match="static pack-budget analysis"):
+        eng.sweep([sc, sc])
+    assert eng.t == 0
 
 
 # ------------------------------------------------------------ CLI & output

@@ -113,7 +113,7 @@ def test_stack_rejects_mismatched_scenarios():
 def test_sweep_shard_map_across_forced_devices(tmp_path):
     """With >1 JAX device the sweep shard_maps the batch axis; forcing two
     host devices in a subprocess must reproduce the single-device owners
-    bit-for-bit (the driver falls back to vmap for uneven batches)."""
+    bit-for-bit."""
     out = tmp_path / "sweep_sharded.npy"
     code = f"""
 import numpy as np, jax
@@ -148,3 +148,62 @@ np.save({str(out)!r}, res.owners)
         [t.scenario() for t in _traces(4)], collect="owners"
     )
     assert np.array_equal(sharded, res.owners)
+
+
+def test_uneven_splits_pad_across_forced_devices(tmp_path):
+    """A cell count (run_trace) or batch (sweep) that does not divide by
+    the device count is padded to a device multiple, not dropped to one
+    device: on four forced host devices both still shard, and both equal
+    the single-device replays bit-for-bit."""
+    out = tmp_path / "uneven.npz"
+    code = f"""
+import numpy as np, jax
+assert jax.device_count() == 4, jax.devices()
+from repro.lease_array import LeaseArrayEngine, random_trace
+tr = random_trace(7, n_ticks=12, n_cells=10, n_acceptors=3, n_proposers=4,
+                  lease_ticks=2, p_attempt=0.5, p_release=0.08,
+                  p_down_flip=0.05, max_delay_ticks=1, p_drop=0.1,
+                  round_ticks=5)
+eng = LeaseArrayEngine(10, n_acceptors=3, n_proposers=4, lease_ticks=2,
+                       round_ticks=5)
+owners, counts = eng.run_trace(tr.scenario())
+spans = {{len(a.sharding.device_set) for a in eng.state}}
+traces = [
+    random_trace(100 + s, n_ticks=12, n_cells=8, n_acceptors=3,
+                 n_proposers=4, lease_ticks=2, p_attempt=0.5,
+                 p_release=0.08, p_down_flip=0.05, round_ticks=2)
+    for s in range(6)
+]
+sweeper = LeaseArrayEngine(8, n_acceptors=3, n_proposers=4, lease_ticks=2,
+                           round_ticks=2)
+res = sweeper.sweep([t.scenario() for t in traces], collect="owners")
+np.savez({str(out)!r}, owners=owners, counts=counts, spans=sorted(spans),
+         sweep=res.owners)
+"""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
+    ).strip()
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (env.get("PYTHONPATH", ""), "src") if p
+    )
+    subprocess.run(
+        [sys.executable, "-c", code], check=True, env=env, timeout=600,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    got = np.load(out)
+    assert list(got["spans"]) == [4]  # the padded state is split 4 ways
+    tr = random_trace(7, n_ticks=12, n_cells=10, n_acceptors=3,
+                      n_proposers=4, lease_ticks=2, p_attempt=0.5,
+                      p_release=0.08, p_down_flip=0.05, max_delay_ticks=1,
+                      p_drop=0.1, round_ticks=5)
+    eng = LeaseArrayEngine(10, n_acceptors=3, n_proposers=4, lease_ticks=2,
+                           round_ticks=5)
+    owners, counts = eng.run_trace(tr.scenario())
+    assert np.array_equal(got["owners"], owners)
+    assert np.array_equal(got["counts"], counts)
+    res = _engine().sweep(
+        [t.scenario() for t in _traces(6)], collect="owners"
+    )
+    assert np.array_equal(got["sweep"], res.owners)
