@@ -28,7 +28,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from .engine import LeaseArrayEngine
+from .engine import LeaseArrayEngine, span
 from .scenario import make_tick
 from .state import NO_PROPOSER
 
@@ -131,65 +131,94 @@ class LeaseArrayDirectory:
         return self._owners
 
     def _tick_once(self) -> np.ndarray:
-        attempt = np.full(self.n_shards, NO_PROPOSER, np.int32)
-        release = np.full(self.n_shards, NO_PROPOSER, np.int32)
-        extend = np.full(self.n_shards, NO_PROPOSER, np.int32)
-        owners = self._owners
-        self._cooldown = np.maximum(self._cooldown - 1, 0)
-        ticks_left = self.engine.ticks_left()
-        by_slot = {w.slot: w for w in self.workers.values()}
-        counts = np.bincount(
-            owners[owners >= 0], minlength=self.engine.n_proposers
-        )
+        tracing = span.is_enabled()
+        with span("lease.dir.tick") as tick_span:
+            attempt = np.full(self.n_shards, NO_PROPOSER, np.int32)
+            release = np.full(self.n_shards, NO_PROPOSER, np.int32)
+            extend = np.full(self.n_shards, NO_PROPOSER, np.int32)
+            owners = self._owners
+            self._cooldown = np.maximum(self._cooldown - 1, 0)
+            ticks_left = self.engine.ticks_left()
 
-        deficits: dict[int, int] = {}
-        for w in self.workers.values():
-            if w.stalled:
-                continue  # a true straggler says nothing — leases just lapse
-            owned = int(counts[w.slot])
-            if w.draining or owned > w.target:
-                mine = np.flatnonzero(owners == w.slot)
-                n_shed = owned if w.draining else owned - w.target
-                release[mine[len(mine) - n_shed:]] = w.slot  # shed highest k
-            if owned < w.target:
-                deficits[w.slot] = w.target - owned
+            with span("lease.dir.shed"):
+                by_slot = {w.slot: w for w in self.workers.values()}
+                counts = np.bincount(
+                    owners[owners >= 0], minlength=self.engine.n_proposers
+                )
+                deficits: dict[int, int] = {}
+                for w in self.workers.values():
+                    if w.stalled:
+                        # a true straggler says nothing — leases just lapse
+                        continue
+                    owned = int(counts[w.slot])
+                    if w.draining or owned > w.target:
+                        mine = np.flatnonzero(owners == w.slot)
+                        n_shed = owned if w.draining else owned - w.target
+                        # shed highest k
+                        release[mine[len(mine) - n_shed:]] = w.slot
+                    if owned < w.target:
+                        deficits[w.slot] = w.target - owned
 
-        # owners inside the renew margin extend in-flight (§6: the extends
-        # plane re-proposes under the live belief; stalled/draining don't)
-        for cell in np.flatnonzero(
-            (owners >= 0)
-            & (ticks_left <= self.renew_margin)
-            & (self._cooldown == 0)
-        ):
-            w = by_slot.get(int(owners[cell]))
-            if w is not None and not w.stalled and not w.draining:
-                if release[cell] != w.slot:  # not shedding this one
-                    extend[cell] = w.slot
-                    self._cooldown[cell] = self._round_trip
+            # owners inside the renew margin extend in-flight (§6: the
+            # extends plane re-proposes under the live belief;
+            # stalled/draining don't)
+            with span("lease.dir.renew") as renew_span:
+                candidates = np.flatnonzero(
+                    (owners >= 0)
+                    & (ticks_left <= self.renew_margin)
+                    & (self._cooldown == 0)
+                )
+                for cell in candidates:
+                    w = by_slot.get(int(owners[cell]))
+                    if w is not None and not w.stalled and not w.draining:
+                        if release[cell] != w.slot:  # not shedding this one
+                            extend[cell] = w.slot
+                            self._cooldown[cell] = self._round_trip
+                if tracing:
+                    n_extends = int(np.count_nonzero(extend >= 0))
+                    renew_span.set_metadata(
+                        candidates=len(candidates), extends=n_extends
+                    )
 
-        # spread unowned cells over deficit workers round-robin (vectorized:
-        # the per-cell Python loop would rival the batched step itself)
-        if deficits:
-            slots = np.array(sorted(deficits), np.int32)
-            wants = np.array([deficits[int(s)] for s in slots])
-            rank = np.concatenate([np.arange(w) for w in wants])
-            seq = np.repeat(slots, wants)[np.argsort(rank, kind="stable")]
-            free = np.flatnonzero(
-                (owners < 0) & (attempt < 0) & (self._cooldown == 0)
-            )
-            k = min(len(seq), len(free))
-            attempt[free[:k]] = seq[:k]
-            self._cooldown[free[:k]] = self._round_trip
-        planes = dict(attempts=attempt, releases=release, extends=extend)
-        if self.max_delay_ticks:
-            planes["delay"] = np.full(
-                self.engine.n_acceptors, self.max_delay_ticks, np.int32
-            )
-        tick = make_tick(
-            n_cells=self.engine.n_cells, n_acceptors=self.engine.n_acceptors,
-            n_proposers=self.engine.n_proposers, **planes,
-        )
-        return self.engine.step(tick).astype(np.int32)
+            # spread unowned cells over deficit workers round-robin
+            # (vectorized: the per-cell Python loop would rival the batched
+            # step itself)
+            with span("lease.dir.assign"):
+                if deficits:
+                    slots = np.array(sorted(deficits), np.int32)
+                    wants = np.array([deficits[int(s)] for s in slots])
+                    rank = np.concatenate([np.arange(w) for w in wants])
+                    seq = np.repeat(slots, wants)[
+                        np.argsort(rank, kind="stable")
+                    ]
+                    free = np.flatnonzero(
+                        (owners < 0) & (attempt < 0) & (self._cooldown == 0)
+                    )
+                    k = min(len(seq), len(free))
+                    attempt[free[:k]] = seq[:k]
+                    self._cooldown[free[:k]] = self._round_trip
+            with span("lease.dir.make_tick"):
+                planes = dict(
+                    attempts=attempt, releases=release, extends=extend
+                )
+                if self.max_delay_ticks:
+                    planes["delay"] = np.full(
+                        self.engine.n_acceptors, self.max_delay_ticks, np.int32
+                    )
+                tick = make_tick(
+                    n_cells=self.engine.n_cells,
+                    n_acceptors=self.engine.n_acceptors,
+                    n_proposers=self.engine.n_proposers, **planes,
+                )
+            t = self.engine.t
+            owners = self.engine.step(tick).astype(np.int32)
+            if tracing:
+                tick_span.set_metadata(
+                    attempts=int(np.count_nonzero(attempt >= 0)),
+                    releases=int(np.count_nonzero(release >= 0)),
+                    extends=n_extends, t=t,
+                )
+            return owners
 
     # -------------------------------------------------------------- queries
     def coverage(self) -> float:

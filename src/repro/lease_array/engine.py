@@ -51,6 +51,7 @@ import numpy as np
 from .netplane import NetPlaneState, init_netplane
 from .ops import (
     _margin_scan_impl,
+    _plane_tick,
     _window_scan_impl,
     lease_plane_tick,
     resolve_backend,
@@ -76,6 +77,10 @@ from .state import (
     lease_quarters,
     rate1_clock,
 )
+
+#: a host span on the profiler's clock; ``span.is_enabled()`` is True only
+#: while a JAX profiler trace is on, and counters are computed only then
+span = jax.profiler.TraceAnnotation
 
 _DEPRECATED_STEP_KWARGS = (
     "per-plane LeaseArrayEngine.step arguments (attempt=, release=, "
@@ -227,10 +232,11 @@ def _cell_sharding_specs(planes_keys):
         for k in planes_keys
     }
     # the clk0/rst0 slots take bare prefix specs: they cover both the
-    # per-node tuples and the None fast path (no leaves) identically
+    # per-node tuples and the None fast path (no leaves) identically; the
+    # grid-step counts are summed over the devices
     return (
         (cells, cells, P(), P(), P(), plane_specs),
-        (cells, cells, cells, cells),
+        (cells, cells, cells, cells, P()),
     )
 
 
@@ -242,16 +248,21 @@ def _trace_fn(
 ):
     """The fused scenario replay, jitted; with >1 device the cell axis is
     shard_map-ed across a 1-D device mesh (cells are independent — the
-    tick math never reduces across N), so a trace uses every device."""
+    tick math never reduces across N), so a trace uses every device.
+    Returns ``_window_scan_impl``'s five outputs, the grid-step counts
+    summed over the devices."""
 
     def run(state, net, t0, clk0, rst0, planes):
-        return _window_scan_impl(
+        *out, steps = _window_scan_impl(
             state, net, t0, clk0, rst0, planes,
             majority=majority, lease_q4=lease_q4, round_q4=round_q4,
             guard_q4=guard_q4, backend=backend, sync=sync, block_n=block_n,
             window=window, restart_guard=restart_guard,
             skip_stable=skip_stable,
         )
+        if n_devices > 1:
+            steps = jax.lax.psum(steps, "cells")
+        return (*out, steps)
 
     if n_devices > 1:
         from jax.sharding import Mesh
@@ -269,10 +280,10 @@ def _trace_fn(
             # registry use), so every device gets an equal share
             n = state.n_cells
             state, net, planes = _pad_cell_axis(state, net, planes, n_devices)
-            out = sharded(state, net, t0, clk0, rst0, planes)
-            if state.n_cells == n:
-                return out
-            return jax.tree.map(lambda a: a[..., :n], out)
+            *out, steps = sharded(state, net, t0, clk0, rst0, planes)
+            if state.n_cells != n:
+                out = jax.tree.map(lambda a: a[..., :n], out)
+            return (*out, steps)
 
     return jax.jit(run)
 
@@ -327,7 +338,7 @@ def _sweep_fn(
             )
         else:
             margins = None
-            _, _, owners, counts = _window_scan_impl(
+            _, _, owners, counts, _ = _window_scan_impl(
                 state, net, t0, clk0, rst0, {**cell_planes, **rest_planes},
                 majority=majority, lease_q4=lease_q4, round_q4=round_q4,
                 guard_q4=guard_q4, backend=backend, sync=sync,
@@ -380,6 +391,25 @@ def _shard_batch(plane, n_devices: int):
     return jax.device_put(v, NamedSharding(_batch_mesh(n_devices), P("b")))
 
 
+def _only_default(plane, default, rows: int = 8) -> bool:
+    """True iff the [T, ...] ``plane`` holds nothing but ``default``. It is
+    read ``rows`` ticks at a time, so a plane in use (a [T, N] extends
+    plane of renewals) is told apart at its first block that is not,
+    without a pass over all of it before the uploads start."""
+    v = np.asarray(plane)
+    return not any(
+        (v[i:i + rows] != default).any() for i in range(0, len(v), rows)
+    )
+
+
+def _grid_counts(steps) -> dict:
+    """A dispatch's window-kernel grid steps, read back from the device:
+    ``windows`` (cell blocks × windows) and ``skipped`` (those that took
+    the quiescent path); both 0 where no delayed window kernel ran."""
+    skipped, windows = np.asarray(steps).tolist()
+    return {"windows": windows, "skipped": skipped}
+
+
 class LeaseArrayEngine:
     def __init__(
         self,
@@ -413,14 +443,15 @@ class LeaseArrayEngine:
         #: the platform's choice unless named (``ops.resolve_backend``)
         self.backend = resolve_backend(backend)
         self.window = int(window)
-        self.state = init_state(n_cells, n_acceptors, n_proposers)
-        self.net: NetPlaneState = init_netplane(n_cells, n_acceptors)
+        with span("lease.init"):  # the fleet's planes, made on the device
+            self.state = init_state(n_cells, n_acceptors, n_proposers)
+            self.net: NetPlaneState = init_netplane(n_cells, n_acceptors)
+            self.last_owner_count = jnp.zeros(n_cells, jnp.int32)
         self.t = 0
         # accumulated local clocks (local quarter-ticks at global tick t);
         # advanced by the scenario's prop_rate/acc_rate planes each tick
         self.prop_clk = np.zeros(n_proposers, np.int32)
         self.acc_clk = np.zeros(n_acceptors, np.int32)
-        self.last_owner_count = jnp.zeros(n_cells, jnp.int32)
         # flips True on the first delayed step; once messages may be in
         # flight, every later tick must run the delayed model too
         self._netplane_active = False
@@ -596,78 +627,94 @@ class LeaseArrayEngine:
         with ``max_delay`` (``random_trace`` enforces both; hand-driven
         schedules must too).
         """
-        if tick is not None and not isinstance(tick, TickInputs):
-            if attempt is not None:
-                raise TypeError(
-                    "pass the attempt row positionally or as attempt=, not both"
+        with span("lease.step") as step_span:
+            with span("lease.validate"):
+                if tick is not None and not isinstance(tick, TickInputs):
+                    if attempt is not None:
+                        raise TypeError(
+                            "pass the attempt row positionally or as "
+                            "attempt=, not both"
+                        )
+                    attempt, tick = tick, None  # legacy positional row
+                elif tick is not None and any(
+                    x is not None
+                    for x in (attempt, release, acc_up, delay, drop)
+                ):
+                    raise TypeError(
+                        "pass planes inside the TickInputs, not alongside it"
+                    )
+                if tick is None:
+                    if any(
+                        x is not None
+                        for x in (attempt, release, acc_up, delay, drop)
+                    ):
+                        warnings.warn(
+                            _DEPRECATED_STEP_KWARGS, DeprecationWarning,
+                            stacklevel=2,
+                        )
+                    # validates ghost proposer ids, shapes, dtypes
+                    tick = make_tick(
+                        n_cells=self.n_cells, n_acceptors=self.n_acceptors,
+                        n_proposers=self.n_proposers,
+                        attempts=attempt, releases=release, acc_up=acc_up,
+                        delay=delay, drop=drop,
+                    )
+                    if delay is not None or drop is not None:
+                        # only once validation passed
+                        self._netplane_active = True
+                else:
+                    tick.validate_for(
+                        n_cells=self.n_cells, n_acceptors=self.n_acceptors,
+                        n_proposers=self.n_proposers,
+                    )
+                    if (
+                        np.asarray(tick.delay).any()
+                        or np.asarray(tick.drop).any()
+                        or tick.corrupted
+                        or tick.restarted
+                        or tick.extended
+                    ):
+                        self._netplane_active = True
+                self._check_pack_budget(
+                    self.t + 1,
+                    int(np.asarray(tick.delay).max(initial=0)),
+                    max(
+                        int(np.asarray(tick.prop_rate).max(initial=0)),
+                        int(np.asarray(tick.acc_rate).max(initial=0)),
+                    ),
+                    self._max_restarts(tick.prop_restart),
                 )
-            attempt, tick = tick, None  # legacy positional attempt row
-        elif tick is not None and any(
-            x is not None for x in (attempt, release, acc_up, delay, drop)
-        ):
-            raise TypeError(
-                "pass planes inside the TickInputs, not alongside it"
-            )
-        if tick is None:
-            if any(
-                x is not None
-                for x in (attempt, release, acc_up, delay, drop)
-            ):
-                warnings.warn(
-                    _DEPRECATED_STEP_KWARGS, DeprecationWarning,
-                    stacklevel=2,
+                if tick.restarted:
+                    # crashes imply in-flight state (restart mode is
+                    # delayed-only) and pin the restart-mode ballot
+                    # encoding from here on
+                    self._netplane_active = True
+                    self._restart_active = True
+            with span("lease.dispatch"):
+                (self.state, self.net, self.last_owner_count,
+                 steps) = _plane_tick(
+                    self.state, self.net, self.t, tick,
+                    majority=self.majority, lease_q4=self.lease_q4,
+                    round_q4=self.round_q4, guard_q4=self.guard_q4,
+                    clk0=self._clk0(), rst0=self._rst0(),
+                    restart_guard=self.restart_guard, backend=self.backend,
+                    sync=not self._netplane_active, window=self.window,
+                    skip_stable=self.skip_stable,
                 )
-            tick = make_tick(  # validates ghost proposer ids, shapes, dtypes
-                n_cells=self.n_cells, n_acceptors=self.n_acceptors,
-                n_proposers=self.n_proposers,
-                attempts=attempt, releases=release, acc_up=acc_up,
-                delay=delay, drop=drop,
-            )
-            if delay is not None or drop is not None:
-                self._netplane_active = True  # only once validation passed
-        else:
-            tick.validate_for(
-                n_cells=self.n_cells, n_acceptors=self.n_acceptors,
-                n_proposers=self.n_proposers,
-            )
-            if (
-                np.asarray(tick.delay).any()
-                or np.asarray(tick.drop).any()
-                or tick.corrupted
-                or tick.restarted
-                or tick.extended
-            ):
-                self._netplane_active = True
-        self._check_pack_budget(
-            self.t + 1,
-            int(np.asarray(tick.delay).max(initial=0)),
-            max(
-                int(np.asarray(tick.prop_rate).max(initial=0)),
-                int(np.asarray(tick.acc_rate).max(initial=0)),
-            ),
-            self._max_restarts(tick.prop_restart),
-        )
-        if tick.restarted:
-            # crashes imply in-flight state (restart mode is delayed-only)
-            # and pin the restart-mode ballot encoding from here on
-            self._netplane_active = True
-            self._restart_active = True
-        self.state, self.net, self.last_owner_count = lease_plane_tick(
-            self.state, self.net, self.t, tick,
-            majority=self.majority, lease_q4=self.lease_q4,
-            round_q4=self.round_q4, guard_q4=self.guard_q4,
-            clk0=self._clk0(), rst0=self._rst0(),
-            restart_guard=self.restart_guard, backend=self.backend,
-            sync=not self._netplane_active, window=self.window,
-            skip_stable=self.skip_stable,
-        )
-        self.t += 1
-        if self._restart_active:
-            self._advance_restarts(
-                tick.acc_restart, tick.prop_restart, tick.acc_rate
-            )
-        self._advance_clocks(tick.prop_rate, tick.acc_rate)
-        return np.asarray(owner_row(self.state))
+                self.t += 1
+                if self._restart_active:
+                    self._advance_restarts(
+                        tick.acc_restart, tick.prop_restart, tick.acc_rate
+                    )
+                self._advance_clocks(tick.prop_rate, tick.acc_rate)
+                owners = owner_row(self.state)
+            with span("lease.wait"):
+                owners.block_until_ready()
+            with span("lease.download"):
+                owners = np.asarray(owners)
+            if span.is_enabled():
+                step_span.set_metadata(**_grid_counts(steps))
+            return owners
 
     # ---------------------------------------------------------- validation
     def _coerce_scenario(self, scenario, releases, acc_up, delay, drop):
@@ -723,70 +770,87 @@ class LeaseArrayEngine:
         Returns (owners [T, N], owner_counts [T, N]) as numpy; the
         engine's state/tick advance past the trace.
         """
-        if attempts is not None:
-            if scenario is not None:
-                raise TypeError(
-                    "pass the attempts plane positionally or as attempts=, "
-                    "not both"
+        tracing = span.is_enabled()
+        with span("lease.run_trace") as run_span:
+            with span("lease.validate"):
+                if attempts is not None:
+                    if scenario is not None:
+                        raise TypeError(
+                            "pass the attempts plane positionally or as "
+                            "attempts=, not both"
+                        )
+                    scenario = attempts  # legacy keyword call sites
+                if not isinstance(scenario, Scenario):
+                    warnings.warn(
+                        _DEPRECATED_TRACE_PLANES, DeprecationWarning,
+                        stacklevel=2,
+                    )
+                scenario = self._coerce_scenario(
+                    scenario, releases, acc_up, delay, drop
                 )
-            scenario = attempts  # legacy keyword call sites
-        if not isinstance(scenario, Scenario):
-            warnings.warn(
-                _DEPRECATED_TRACE_PLANES, DeprecationWarning, stacklevel=2
-            )
-        scenario = self._coerce_scenario(
-            scenario, releases, acc_up, delay, drop
-        )
-        T = scenario.n_ticks
-        restarted = scenario.restarted
-        sync = self._pick_model(
-            netplane,
-            scenario.delayed or scenario.corrupted or restarted
-            or scenario.extended,
-        )
-        if T == 0:
-            empty = np.zeros((0, self.n_cells), np.int32)
-            return empty, empty.copy()
-        dmax = int(np.asarray(scenario.delay).max(initial=0))
-        rmax = max(
-            int(np.asarray(scenario.prop_rate).max(initial=0)),
-            int(np.asarray(scenario.acc_rate).max(initial=0)),
-        )
-        mr = self._max_restarts(scenario.prop_restart)
-        self._check_pack_budget(self.t + T, dmax, rmax, mr)
-        self._static_bound_check(self.t + T, dmax, rmax, mr)
-        if restarted:
-            self._restart_active = True  # pins the restart ballot encoding
-        # all-default corruption/restart/extends planes stay host-side:
-        # the honest replay never compiles the faulted tick variants
-        # (bit-identical jaxpr, zero extra uploads); once restart mode is
-        # pinned, rst0 (not the planes) keeps it on across quiet dispatches
-        planes = {
-            k: jnp.asarray(v) for k, v in scenario.planes.items()
-            if not (
-                k in CORRUPTION_PLANES + RESTART_PLANES + EXTEND_PLANES
-                and (np.asarray(v) == PLANES[k].default).all()
-            )
-        }
-        fn = _trace_fn(
-            self.majority, self.lease_q4, self.round_q4, self.guard_q4,
-            self.backend, sync, 512, self.window, len(jax.devices()),
-            tuple(planes),
-            self.restart_guard, self.skip_stable,
-        )
-        self.state, self.net, owners, counts = fn(
-            self.state, self.net, jnp.int32(self.t), self._clk0(),
-            self._rst0(), planes
-        )
-        self.t += int(T)
-        if self._restart_active:
-            self._advance_restarts(
-                scenario.acc_restart, scenario.prop_restart,
-                scenario.acc_rate,
-            )
-        self._advance_clocks(scenario.prop_rate, scenario.acc_rate)
-        self.last_owner_count = counts[-1]
-        return np.asarray(owners), np.asarray(counts)
+                T = scenario.n_ticks
+                restarted = scenario.restarted
+                sync = self._pick_model(
+                    netplane,
+                    scenario.delayed or scenario.corrupted or restarted
+                    or scenario.extended,
+                )
+                if T == 0:
+                    empty = np.zeros((0, self.n_cells), np.int32)
+                    return empty, empty.copy()
+                dmax = int(np.asarray(scenario.delay).max(initial=0))
+                rmax = max(
+                    int(np.asarray(scenario.prop_rate).max(initial=0)),
+                    int(np.asarray(scenario.acc_rate).max(initial=0)),
+                )
+                mr = self._max_restarts(scenario.prop_restart)
+                self._check_pack_budget(self.t + T, dmax, rmax, mr)
+                self._static_bound_check(self.t + T, dmax, rmax, mr)
+                if restarted:
+                    # pins the restart ballot encoding
+                    self._restart_active = True
+                # all-default corruption/restart/extends planes stay
+                # host-side: the honest replay never compiles the faulted
+                # tick variants (bit-identical jaxpr, zero extra uploads);
+                # once restart mode is pinned, rst0 (not the planes) keeps
+                # it on across quiet dispatches
+                kept = {
+                    k: v for k, v in scenario.planes.items()
+                    if not (
+                        k in CORRUPTION_PLANES + RESTART_PLANES + EXTEND_PLANES
+                        and _only_default(v, PLANES[k].default)
+                    )
+                }
+            with span("lease.upload"):
+                planes = {k: jnp.asarray(v) for k, v in kept.items()}
+                if tracing:  # the span ends with the planes on the device
+                    jax.block_until_ready(planes)
+            with span("lease.dispatch"):
+                fn = _trace_fn(
+                    self.majority, self.lease_q4, self.round_q4, self.guard_q4,
+                    self.backend, sync, 512, self.window, len(jax.devices()),
+                    tuple(planes),
+                    self.restart_guard, self.skip_stable,
+                )
+                self.state, self.net, owners, counts, steps = fn(
+                    self.state, self.net, jnp.int32(self.t), self._clk0(),
+                    self._rst0(), planes
+                )
+                self.t += int(T)
+                if self._restart_active:
+                    self._advance_restarts(
+                        scenario.acc_restart, scenario.prop_restart,
+                        scenario.acc_rate,
+                    )
+                self._advance_clocks(scenario.prop_rate, scenario.acc_rate)
+                self.last_owner_count = counts[-1]
+            with span("lease.wait"):
+                jax.block_until_ready((owners, counts))
+            with span("lease.download"):
+                owners, counts = np.asarray(owners), np.asarray(counts)
+            if tracing:
+                run_span.set_metadata(**_grid_counts(steps))
+            return owners, counts
 
     # ----------------------------------------------------------- the sweep
     def sweep(
@@ -966,15 +1030,18 @@ class LeaseArrayEngine:
         sees it (0 if unowned). Owner expiries live in the owning
         proposer's local time, so remaining time is measured against that
         proposer's accumulated clock (= ``4t`` when nothing drifts)."""
-        expiry = np.asarray(
-            jnp.max(
+        with span("lease.ticks_left"):
+            expiry = jnp.max(
                 jnp.where(self.state.owner_mask > 0, self.state.owner_expiry, 0),
                 axis=0,
             )
-        )
-        owners = np.asarray(owner_row(self.state))
-        clk = np.where(
-            owners == NO_PROPOSER, 0,
-            self.prop_clk[np.clip(owners, 0, self.n_proposers - 1)],
-        )
-        return np.maximum(expiry - clk, 0) // QUARTERS
+            owners = owner_row(self.state)
+            with span("lease.wait"):
+                jax.block_until_ready((expiry, owners))
+            with span("lease.download"):
+                expiry, owners = np.asarray(expiry), np.asarray(owners)
+            clk = np.where(
+                owners == NO_PROPOSER, 0,
+                self.prop_clk[np.clip(owners, 0, self.n_proposers - 1)],
+            )
+            return np.maximum(expiry - clk, 0) // QUARTERS

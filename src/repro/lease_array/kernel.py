@@ -130,13 +130,15 @@ def _window_geometry(n_cells: int, n_ticks: int, block_n: int, window: int):
 def _launch_plan(
     rows, n_acceptors: int, n_cells: int, n_proposers: int, n_ticks: int,
     block_n: int, window: int, bcast_rows: tuple[tuple[int, int], ...],
-    n_cell_planes: int = 2,
+    n_cell_planes: int = 2, skip_row: bool = False,
 ) -> LaunchPlan:
     """Shared plan builder: ``rows`` describes the resident state planes
     (None -> A rows), ``n_cell_planes`` how many [T, N] cell-plane streams
     follow them (attempts/releases, plus the §6 extends stream), and
     ``bcast_rows`` the trailing cell-independent streams as (rows, cols)
-    pairs."""
+    pairs. ``skip_row`` appends a resident [1, N] output after the owners
+    and counts: each cell block's count of windows that took the
+    quiescent path."""
     A, N, T = n_acceptors, n_cells, n_ticks
     block_n, tw, n_windows = _window_geometry(N, T, block_n, window)
     grid = (N // block_n, n_windows)
@@ -155,12 +157,14 @@ def _launch_plan(
         ((2,), *state_shapes, *(cell_shape,) * n_cell_planes)
         + tuple((n_windows, tw, r, c) for r, c in bcast_rows)
     )
+    skip_specs = tuple(_state_specs((1,), A, block_n)) if skip_row else ()
+    skip_shapes = ((1, N),) if skip_row else ()
     return LaunchPlan(
         grid=grid,
         in_specs=in_specs,
-        out_specs=(*state_specs, cell_spec, cell_spec),
+        out_specs=(*state_specs, cell_spec, cell_spec, *skip_specs),
         in_shapes=in_shapes,
-        out_shapes=(*state_shapes, cell_shape, cell_shape),
+        out_shapes=(*state_shapes, cell_shape, cell_shape, *skip_shapes),
         block_n=block_n,
         tw=tw,
         n_windows=n_windows,
@@ -193,7 +197,8 @@ def delayed_launch_plan(
     equivocation masks) to the streamed planes; ``restart`` appends the
     four crash/restart columns (acceptor restart + deaf-window masks
     [A, 1], proposer restart + running restart counters [P, 1]) — the
-    honest launch is geometry-identical to the pre-falsifier kernel."""
+    honest launch is geometry-identical to the pre-falsifier kernel. The
+    outputs end with the resident [1, N] skip-count row."""
     A, P = n_acceptors, n_proposers
     bcast = ((A, 1), (P, 1), (A, 1), (P, A))
     if corrupt:
@@ -202,7 +207,7 @@ def delayed_launch_plan(
         bcast += ((A, 1), (A, 1), (P, 1), (P, 1))
     return _launch_plan(
         _LEASE_ROWS + _NET_ROWS, A, n_cells, P, n_ticks, block_n, window,
-        bcast_rows=bcast, n_cell_planes=3 if extend else 2,
+        bcast_rows=bcast, n_cell_planes=3 if extend else 2, skip_row=True,
     )
 
 
@@ -323,9 +328,15 @@ def _delayed_window_kernel(
         extra += 2
     rst_refs = ins[extra:extra + 4] if restart else None
     st_refs = outs[:n_state]
-    own_ref, cnt_ref = outs[n_state], outs[n_state + 1]
+    own_ref, cnt_ref, skip_ref = outs[n_state:n_state + 3]
     _init_resident(pl.program_id(1), ins[:n_state], st_refs)
     t_base, n_ticks = _window_bounds(sc_ref, tw)
+
+    # the block's count of windows that took the quiescent path, resident
+    # across the window axis like the state rows
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        skip_ref[...] = jnp.zeros_like(skip_ref)
 
     def body(tau, carry):
         lease, net = carry[:N_LEASE], carry[N_LEASE:]
@@ -377,6 +388,7 @@ def _delayed_window_kernel(
         cnt_row = (st_refs[_OWN_LEASE][...] > 0).astype(jnp.int32)
         own_ref[...] = jnp.broadcast_to(own_row[None], own_ref.shape)
         cnt_ref[...] = jnp.broadcast_to(cnt_row[None], cnt_ref.shape)
+        skip_ref[...] = skip_ref[...] + 1
 
     @pl.when(jnp.logical_not(skip))
     def _():
@@ -440,6 +452,7 @@ def lease_window_sync_pallas(
         out_specs=list(plan.out_specs),
         out_shape=[sds(s, jnp.int32) for s in plan.out_shapes],
         interpret=interpret,
+        name="lease_window_sync",
     )(
         jnp.stack([jnp.asarray(t0, jnp.int32), jnp.int32(T)]),
         *packed,
@@ -480,10 +493,12 @@ def lease_window_delayed_pallas(
     acc_deaf=None,      # [T, A] post-restart deaf-window mask
     prop_restart=None,  # [T, P] proposer crash+restart mask
     prop_rc=None,       # [T, P] running per-proposer restart counters
-) -> tuple[PackedLeaseState, NetPlaneState, jax.Array, jax.Array]:
+) -> tuple[PackedLeaseState, NetPlaneState, jax.Array, jax.Array, jax.Array]:
     """Replay T delayed-model ticks in ONE kernel launch (state AND the
     in-flight netplane stay VMEM-resident across windows). Returns
-    (packed_state', net', owners [T, N], counts [T, N]). Passing
+    (packed_state', net', owners [T, N], counts [T, N], steps [2]), where
+    ``steps`` holds the grid steps (cell block, window) that took the
+    quiescent path, then every grid step of the launch. Passing
     ``extends`` streams the §6 owner-extension ids as a third [T, N]
     cell plane and compiles the extend gate. Passing either corruption
     mask streams both as extra [A, 1] broadcast columns and compiles the
@@ -529,6 +544,7 @@ def lease_window_delayed_pallas(
         out_specs=list(plan.out_specs),
         out_shape=[sds(s, jnp.int32) for s in plan.out_shapes],
         interpret=interpret,
+        name="lease_window_delayed",
     )(
         jnp.stack([jnp.asarray(t0, jnp.int32), jnp.int32(T)]),
         *packed,
@@ -566,7 +582,13 @@ def lease_window_delayed_pallas(
     new_net = NetPlaneState(*outs[N_LEASE:n_state])
     owners = outs[n_state].reshape(n_windows * tw, N)[:T]
     counts = outs[n_state + 1].reshape(n_windows * tw, N)[:T]
-    return new_packed, new_net, owners, counts
+    # every lane of a block's row holds the block's count: read one each
+    skip_row = outs[n_state + 2]
+    skipped = jax.lax.slice(
+        skip_row, (0, 0), skip_row.shape, (1, plan.block_n)
+    ).sum()
+    steps = jnp.stack([skipped, jnp.int32(plan.grid[0] * plan.grid[1])])
+    return new_packed, new_net, owners, counts, steps
 
 
 def delayed_kernel_args(

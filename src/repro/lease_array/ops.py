@@ -229,7 +229,10 @@ def _window_scan_impl(
     arrays); ``clk0`` the (prop [P], acc [A]) local-clock offsets at
     ``t0`` (None = the rate-1 reading ``4·t0``); ``rst0`` the
     (restart-counter [P], deaf-until [A]) restart history at ``t0``
-    (None = fresh). Returns (state', net', owners [T, N], counts [T, N])."""
+    (None = fresh). Returns (state', net', owners [T, N], counts [T, N],
+    steps [2]): ``steps`` counts the window kernel's grid steps that took
+    the quiescent path, then all of its grid steps (both 0 where no
+    delayed window kernel ran: the jnp scan and the synchronous kernel)."""
     backend = resolve_backend(backend)
     P = state.n_proposers
     A, N = state.highest_promised.shape
@@ -351,7 +354,8 @@ def _window_scan_impl(
             )
             new_net = NetPlaneState(*netc)
         new_state = unpack_state(PackedLeaseState(*lease), P)
-        return new_state, new_net, owners.reshape(T, N), counts.reshape(T, N)
+        return (new_state, new_net, owners.reshape(T, N),
+                counts.reshape(T, N), jnp.zeros(2, jnp.int32))
 
     interpret = backend == "pallas"
     padded, n = _pad_packed(packed, block_n)
@@ -369,6 +373,7 @@ def _window_scan_impl(
             interpret=interpret,
         )
         new_net = net
+        steps = jnp.zeros(2, jnp.int32)
     else:
         net_p = _pad_net(net, block_n)
         rst_kw = (
@@ -376,7 +381,7 @@ def _window_scan_impl(
                  prop_rc=rc)
             if restart else {}
         )
-        padded, net_p, owners, counts = lease_window_delayed_pallas(
+        padded, net_p, owners, counts, steps = lease_window_delayed_pallas(
             padded, net_p, t0, attempts_p, releases_p, acc_up, pclk, aclk,
             link, extends=ext_p, stale=stale, equiv=equiv, **rst_kw,
             majority=majority, lease_q4=lease_q4, round_q4=round_q4,
@@ -387,7 +392,7 @@ def _window_scan_impl(
     new_state = unpack_state(
         PackedLeaseState(*(a[:, :n] for a in padded)), P
     )
-    return new_state, new_net, owners[:, :n], counts[:, :n]
+    return new_state, new_net, owners[:, :n], counts[:, :n], steps
 
 
 _window_scan_jit = functools.partial(
@@ -763,10 +768,10 @@ def lease_window_scan(
         guard_q4=guard_q4, backend=backend, sync=sync, block_n=block_n,
         window=window, restart_guard=restart_guard,
         skip_stable=skip_stable,
-    )
+    )[:4]
 
 
-def lease_plane_tick(
+def _plane_tick(
     state: LeaseArrayState,
     net: NetPlaneState,
     t,
@@ -784,24 +789,9 @@ def lease_plane_tick(
     sync: bool = False,
     window: int = 16,
     skip_stable: bool = True,
-) -> tuple[LeaseArrayState, NetPlaneState, jax.Array]:
-    """Advance all cells one tick.
-
-    ``sync=True`` runs the zero-delay synchronous model (``net`` passes
-    through untouched; the tick's delay/drop planes are ignored);
-    ``sync=False`` runs the delayed in-flight model with the tick's
-    ``[P, A]`` link matrices. ``guard_q4``/``clk0`` are the drift
-    parameters (see :func:`lease_window_scan`); the tick's
-    ``prop_rate``/``acc_rate`` planes advance the clocks *after* this
-    tick's deadlines are evaluated, so a stateful caller carries
-    ``clk0 + rate`` into the next tick (``engine.step`` does). backend:
-    None (the platform's choice, :func:`resolve_backend`), "jnp"
-    (reference), "pallas" (kernel, interpret mode — CPU only) or
-    "pallas_tpu" (compiled kernel, TPU only). Returns
-    (new_state, new_net, owner_count[N]) — owner_count is the per-cell
-    number of proposers who believe they own it (>1 would be a §4
-    violation).
-    """
+) -> tuple[LeaseArrayState, NetPlaneState, jax.Array, jax.Array]:
+    """:func:`lease_plane_tick`, and the window kernel's grid-step counts
+    of the dispatch (``steps``, see ``_window_scan_impl``)."""
     if guard_q4 is None:
         guard_q4 = lease_q4
 
@@ -833,14 +823,42 @@ def lease_plane_tick(
         n_proposers=state.n_proposers, lease_q4=lease_q4, sync=sync,
         clk0=clk0, rst0=rst0,
     )
-    new_state, new_net, _, counts = _window_scan_jit(
+    new_state, new_net, _, counts, steps = _window_scan_jit(
         state, net, t, clk0, rst0, planes,
         majority=majority, lease_q4=lease_q4, round_q4=round_q4,
         guard_q4=guard_q4, backend=backend, sync=sync, block_n=block_n,
         window=window, restart_guard=restart_guard,
         skip_stable=skip_stable,
     )
-    return new_state, new_net, counts[0]
+    return new_state, new_net, counts[0], steps
+
+
+def lease_plane_tick(
+    state: LeaseArrayState, net: NetPlaneState, t, tick: TickInputs, **kw,
+) -> tuple[LeaseArrayState, NetPlaneState, jax.Array]:
+    """Advance all cells one tick.
+
+    ``sync=True`` runs the zero-delay synchronous model (``net`` passes
+    through untouched; the tick's delay/drop planes are ignored);
+    ``sync=False`` runs the delayed in-flight model with the tick's
+    ``[P, A]`` link matrices. ``guard_q4``/``clk0`` are the drift
+    parameters (see :func:`lease_window_scan`); the tick's
+    ``prop_rate``/``acc_rate`` planes advance the clocks *after* this
+    tick's deadlines are evaluated, so a stateful caller carries
+    ``clk0 + rate`` into the next tick (``engine.step`` does). backend:
+    None (the platform's choice, :func:`resolve_backend`), "jnp"
+    (reference), "pallas" (kernel, interpret mode — CPU only) or
+    "pallas_tpu" (compiled kernel, TPU only). Returns
+    (new_state, new_net, owner_count[N]) — owner_count is the per-cell
+    number of proposers who believe they own it (>1 would be a §4
+    violation).
+
+    Keywords: ``majority``, ``lease_q4`` and ``round_q4`` (required),
+    ``guard_q4``, ``clk0``, ``rst0``, ``restart_guard``, ``backend``,
+    ``block_n``, ``sync``, ``window`` and ``skip_stable``, as
+    :func:`lease_window_scan` takes them.
+    """
+    return _plane_tick(state, net, t, tick, **kw)[:3]
 
 
 # --------------------------------------------------------------------------

@@ -252,3 +252,16 @@ def test_build_shard_manager_backend_dispatch():
     assert isinstance(m, ShardLeaseManager)
     with pytest.raises(ValueError):
         build_shard_manager(64, backend="event")  # event path needs a Cell
+
+
+@pytest.mark.parametrize("tick", [None, 0, 7, 8, 19])
+def test_only_default_finds_a_value_in_any_block(tick):
+    """run_trace's default-plane test reads the plane block by block; a
+    value off the default in any tick, the last block's included, is
+    found."""
+    from repro.lease_array.engine import _only_default
+
+    plane = np.full((20, 64), NO_PROPOSER, np.int32)
+    if tick is not None:
+        plane[tick, 63] = 2
+    assert _only_default(plane, NO_PROPOSER) == (tick is None)
