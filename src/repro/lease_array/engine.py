@@ -64,6 +64,7 @@ from .scenario import (
     RESTART_PLANES,
     Scenario,
     TickInputs,
+    check_bounds,
     make_tick,
     plane_digest,
 )
@@ -402,6 +403,36 @@ def _only_default(plane, default, rows: int = 8) -> bool:
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _bounds_fn(planes_keys: tuple):
+    """Jitted planes -> int32 [K, 2]: the (min, max) of each of the
+    ``planes_keys`` planes, in one dispatch. An empty plane reads (int32
+    max, int32 min), which ``check_bounds`` passes, as the host checks
+    pass an empty array."""
+    lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+
+    def bounds(planes):
+        return jnp.stack([
+            jnp.stack([jnp.min(v, initial=hi), jnp.max(v, initial=lo)])
+            for v in (planes[k].astype(jnp.int32) for k in planes_keys)
+        ])
+
+    return jax.jit(bounds)
+
+
+def _device_checked(planes: dict) -> tuple:
+    """The bounded [T, N] planes (``PlaneSpec.bounded`` with the cell
+    axis) whose bounds ``run_trace`` reads on the device after the upload
+    instead of in host passes before it; the upload keeps their values
+    whole (an int32 or narrower dtype; a wider plane is checked on the
+    host)."""
+    return tuple(
+        k for k, v in planes.items()
+        if "N" in PLANES[k].dims and PLANES[k].bounded
+        and np.can_cast(np.asarray(v).dtype, np.int32)
+    )
+
+
 def _grid_counts(steps) -> dict:
     """A dispatch's window-kernel grid steps, read back from the device:
     ``windows`` (cell blocks × windows) and ``skipped`` (those that took
@@ -718,6 +749,10 @@ class LeaseArrayEngine:
 
     # ---------------------------------------------------------- validation
     def _coerce_scenario(self, scenario, releases, acc_up, delay, drop):
+        """The call's Scenario, checked on the host but for the bounds of
+        its [T, N] planes: ``run_trace`` reads those on the device once
+        they are uploaded (``_device_checked``). The legacy raw-array form
+        builds its Scenario, which checks every plane on the host."""
         if not isinstance(scenario, Scenario):
             scenario = Scenario.build(
                 n_cells=self.n_cells, n_acceptors=self.n_acceptors,
@@ -729,13 +764,14 @@ class LeaseArrayEngine:
             scenario.validate_for(
                 n_cells=self.n_cells, n_acceptors=self.n_acceptors,
                 n_proposers=self.n_proposers,
+                skip_bounds=_device_checked(scenario.planes),
             )
         return scenario
 
-    def _pick_model(self, netplane, delayed: bool, *, mutate: bool = True) -> bool:
-        """Returns sync=True/False. With ``mutate`` the engine flips onto
-        the netplane permanently (run_trace/step); a read-only caller
-        (sweep) passes ``mutate=False`` and the engine is left untouched."""
+    def _pick_model(self, netplane, delayed: bool) -> bool:
+        """Returns sync=True/False; the engine itself is left untouched
+        (``run_trace`` pins the netplane, ``_netplane_active = not sync``,
+        once its checks have passed)."""
         if netplane is False and (delayed or self._netplane_active):
             raise ValueError(
                 "netplane=False but the scenario carries nonzero delay/drop, "
@@ -743,8 +779,6 @@ class LeaseArrayEngine:
                 "flight); the synchronous model cannot honor them"
             )
         wants_net = bool(netplane) or (netplane is None and delayed)
-        if mutate and wants_net:
-            self._netplane_active = True
         return not (wants_net or self._netplane_active)
 
     # ------------------------------------------------------------ bulk path
@@ -769,6 +803,12 @@ class LeaseArrayEngine:
         raises rather than silently dropping them.
         Returns (owners [T, N], owner_counts [T, N]) as numpy; the
         engine's state/tick advance past the trace.
+
+        The checks run in two ``lease.validate`` spans: on the host before
+        the upload (shapes, the planes without a cell axis, the pack
+        budget), and on the device after it, where one reduction reads the
+        bounds of the [T, N] proposer-id planes. A refused scenario raises
+        before the dispatch and leaves the engine as it was.
         """
         tracing = span.is_enabled()
         with span("lease.run_trace") as run_span:
@@ -789,13 +829,28 @@ class LeaseArrayEngine:
                     scenario, releases, acc_up, delay, drop
                 )
                 T = scenario.n_ticks
-                restarted = scenario.restarted
+                # all-default corruption/restart/extends planes stay
+                # host-side: the honest replay never compiles the faulted
+                # tick variants (bit-identical jaxpr, zero extra uploads);
+                # once restart mode is pinned, rst0 (not the planes) keeps
+                # it on across quiet dispatches. A stripped plane is valid:
+                # it holds nothing but its default
+                kept = {
+                    k: v for k, v in scenario.planes.items()
+                    if not (
+                        k in CORRUPTION_PLANES + RESTART_PLANES + EXTEND_PLANES
+                        and _only_default(v, PLANES[k].default)
+                    )
+                }
+                restarted = any(k in kept for k in RESTART_PLANES)
                 sync = self._pick_model(
                     netplane,
-                    scenario.delayed or scenario.corrupted or restarted
-                    or scenario.extended,
+                    scenario.delayed or restarted or any(
+                        k in kept for k in CORRUPTION_PLANES + EXTEND_PLANES
+                    ),
                 )
                 if T == 0:
+                    self._netplane_active = not sync
                     empty = np.zeros((0, self.n_cells), np.int32)
                     return empty, empty.copy()
                 dmax = int(np.asarray(scenario.delay).max(initial=0))
@@ -806,26 +861,33 @@ class LeaseArrayEngine:
                 mr = self._max_restarts(scenario.prop_restart)
                 self._check_pack_budget(self.t + T, dmax, rmax, mr)
                 self._static_bound_check(self.t + T, dmax, rmax, mr)
-                if restarted:
-                    # pins the restart ballot encoding
-                    self._restart_active = True
-                # all-default corruption/restart/extends planes stay
-                # host-side: the honest replay never compiles the faulted
-                # tick variants (bit-identical jaxpr, zero extra uploads);
-                # once restart mode is pinned, rst0 (not the planes) keeps
-                # it on across quiet dispatches
-                kept = {
-                    k: v for k, v in scenario.planes.items()
-                    if not (
-                        k in CORRUPTION_PLANES + RESTART_PLANES + EXTEND_PLANES
-                        and _only_default(v, PLANES[k].default)
-                    )
-                }
             with span("lease.upload"):
                 planes = {k: jnp.asarray(v) for k, v in kept.items()}
                 if tracing:  # the span ends with the planes on the device
                     jax.block_until_ready(planes)
+            with span("lease.validate") as check_span:
+                # the [T, N] planes' bounds, read on the device where they
+                # now are: a few int32s come back, not passes over them
+                checked = _device_checked(kept)
+                if checked:
+                    bounds = np.asarray(
+                        _bounds_fn(checked)({k: planes[k] for k in checked})
+                    )
+                    for k, (lo, hi) in zip(checked, bounds.tolist()):
+                        check_bounds(
+                            PLANES[k], lo, hi, self.n_proposers, "Scenario"
+                        )
+                if tracing:
+                    check_span.set_metadata(
+                        planes=len(checked),
+                        bytes=sum(planes[k].nbytes for k in checked),
+                    )
             with span("lease.dispatch"):
+                # the checks passed: only now does the engine change
+                self._netplane_active = not sync
+                if restarted:
+                    # pins the restart ballot encoding
+                    self._restart_active = True
                 fn = _trace_fn(
                     self.majority, self.lease_q4, self.round_q4, self.guard_q4,
                     self.backend, sync, 512, self.window, len(jax.devices()),
@@ -975,7 +1037,6 @@ class LeaseArrayEngine:
         # delayed tick)
         sync = self._pick_model(
             netplane, delayed or corrupt or restarted or extended,
-            mutate=False,
         )
         mr = self._max_restarts(stacked.planes.get("prop_restart"))
         self._check_pack_budget(self.t + T, dmax, rmax, mr)

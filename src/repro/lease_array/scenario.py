@@ -62,7 +62,7 @@ __all__ = [
     "Scenario",
     "TickInputs",
     "make_tick",
-    "validate_proposer_ids",
+    "check_bounds",
 ]
 
 
@@ -81,6 +81,12 @@ class PlaneSpec(NamedTuple):
     proposer_ids: bool = False
     #: entries below this raise at build/validate time (None = unchecked)
     min_value: Optional[int] = None
+
+    @property
+    def bounded(self) -> bool:
+        """True iff the plane's entries have bounds to check
+        (``check_bounds``)."""
+        return self.proposer_ids or self.min_value is not None
 
 
 #: the plane registry — insertion order is the canonical plane order
@@ -236,37 +242,30 @@ def plane_digest(planes: dict) -> str:
     return h.hexdigest()[:12]
 
 
-def validate_proposer_ids(arr, n_proposers: int) -> None:
-    """Reject ids outside [-1, n_proposers): an out-of-range id would lease
-    cells to a proposer the plane has no row for — a ghost owner nobody
-    believes in. Shared by ``LeaseArrayEngine.step`` and every Scenario
-    build (so ``run_trace`` traces are checked too)."""
-    a = np.asarray(arr)
-    if a.size == 0:
-        return
-    hi, lo = int(a.max()), int(a.min())
-    if hi >= n_proposers:
-        raise ValueError(
-            f"proposer id {hi} out of range "
-            f"(plane has {n_proposers} proposers)"
-        )
-    if lo < NO_PROPOSER:
-        raise ValueError(
-            f"proposer id {lo} out of range ({NO_PROPOSER} means no proposer)"
-        )
-
-
-def _dim_sizes(n_cells: int, n_acceptors: int, n_proposers: int) -> dict[str, int]:
-    return {"N": int(n_cells), "A": int(n_acceptors), "P": int(n_proposers)}
-
-
-def _check_min_value(spec: PlaneSpec, arr: np.ndarray, what: str) -> None:
-    """Registry-driven range floor: delays must be >= 0 (legs cannot land
-    in the past), clock rates >= 1 (a rate-0 clock freezes its timers)."""
-    if spec.min_value is None or arr.size == 0:
-        return
-    lo = int(arr.min())
-    if lo < spec.min_value:
+def check_bounds(
+    spec: PlaneSpec, lo: int, hi: int, n_proposers: int, what: str
+) -> None:
+    """Refuse a ``spec`` plane whose entries span ``[lo, hi]``. A proposer
+    id outside [-1, n_proposers) would lease cells to a proposer the plane
+    has no row for — a ghost owner nobody believes in; an entry below the
+    plane's ``min_value`` breaks its floor: delays must be >= 0 (legs
+    cannot land in the past), clock rates >= 1 (a rate-0 clock freezes its
+    timers). The one place these messages are written: the host checks
+    (``Scenario.build``, ``make_tick``, ``validate_for``) and
+    ``engine.run_trace``'s check of the bounds it reads on the device
+    both raise here."""
+    if spec.proposer_ids:
+        if hi >= n_proposers:
+            raise ValueError(
+                f"proposer id {hi} out of range "
+                f"(plane has {n_proposers} proposers)"
+            )
+        if lo < NO_PROPOSER:
+            raise ValueError(
+                f"proposer id {lo} out of range "
+                f"({NO_PROPOSER} means no proposer)"
+            )
+    if spec.min_value is not None and lo < spec.min_value:
         kind = (
             "negative entries" if spec.min_value == 0
             else f"entries below {spec.min_value}"
@@ -275,6 +274,19 @@ def _check_min_value(spec: PlaneSpec, arr: np.ndarray, what: str) -> None:
             f"{what} plane {spec.name!r} has {kind} (min {lo}); "
             f"valid entries are >= {spec.min_value}"
         )
+
+
+def _dim_sizes(n_cells: int, n_acceptors: int, n_proposers: int) -> dict[str, int]:
+    return {"N": int(n_cells), "A": int(n_acceptors), "P": int(n_proposers)}
+
+
+def _check_plane(
+    spec: PlaneSpec, arr: np.ndarray, n_proposers: int, what: str
+) -> None:
+    """``check_bounds`` on a host array's own bounds (an empty or
+    unbounded plane passes unread)."""
+    if spec.bounded and arr.size:
+        check_bounds(spec, int(arr.min()), int(arr.max()), n_proposers, what)
 
 
 def _coerce_plane(
@@ -302,9 +314,7 @@ def _coerce_plane(
                     ax = len(lead) + spec.dims.index(d)
                     arr = np.expand_dims(arr, ax)
                 arr = np.broadcast_to(arr, shape).copy()
-            if spec.proposer_ids:
-                validate_proposer_ids(arr, sizes["P"])
-            _check_min_value(spec, arr, what)
+            _check_plane(spec, arr, sizes["P"], what)
             return arr
     accepted = " or ".join(
         str(lead + tuple(sizes[d] for d in dims)) for dims in forms
@@ -403,12 +413,16 @@ class _PlaneBundle:
         ))
 
     def validate_for(
-        self, *, n_cells: int, n_acceptors: int, n_proposers: int
+        self, *, n_cells: int, n_acceptors: int, n_proposers: int,
+        skip_bounds: Iterable[str] = (),
     ) -> None:
         """Check every plane against an engine's geometry (shape + ids +
         delay sign). ``build``/``make_tick`` output always passes;
         hand-rolled pytrees are checked here before they reach the step or
-        the scanner."""
+        the scanner. The planes named in ``skip_bounds`` have their shapes
+        checked and their entries left unread: their caller checks those
+        bounds itself (``engine.run_trace`` reads them on the device after
+        the upload) through the same ``check_bounds``."""
         sizes = _dim_sizes(n_cells, n_acceptors, n_proposers)
         lead: tuple[int, ...] = ()
         if self._lead_ndim:
@@ -424,9 +438,8 @@ class _PlaneBundle:
                     f"{what} plane {name!r} has shape {arr.shape}; "
                     f"engine geometry wants {want}"
                 )
-            if spec.proposer_ids:
-                validate_proposer_ids(arr, sizes["P"])
-            _check_min_value(spec, arr, what)
+            if name not in skip_bounds:
+                _check_plane(spec, arr, sizes["P"], what)
 
     def __repr__(self) -> str:
         inner = ", ".join(

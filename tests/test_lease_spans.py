@@ -19,8 +19,8 @@ from repro.lease_array import engine as engine_mod
 from repro.lease_array.directory import LeaseArrayDirectory
 from repro.lease_array.state import NO_PROPOSER
 
-RUN_TRACE = ["lease.validate", "lease.upload", "lease.dispatch",
-             "lease.wait", "lease.download"]
+RUN_TRACE = ["lease.validate", "lease.upload", "lease.validate",
+             "lease.dispatch", "lease.wait", "lease.download"]
 STEP = ["lease.validate", "lease.dispatch", "lease.wait", "lease.download"]
 DIR_TICK = ["lease.ticks_left", "lease.dir.shed", "lease.dir.renew",
             "lease.dir.assign", "lease.dir.make_tick", "lease.step"]
@@ -33,6 +33,9 @@ class Node:
 
     def kids(self):
         return [c.name for c in self.children]
+
+    def named(self, name):
+        return [c for c in self.children if c.name == name]
 
 
 def lease_roots(trace_dir) -> list:
@@ -93,6 +96,8 @@ def test_engine_and_directory_record_the_span_tree(tmp_path):
     run = roots[1]
     assert run.kids() == RUN_TRACE
     assert set(run.stats) == {"windows", "skipped"}
+    # the device check read the attempts and releases planes, 6 x 128 each
+    assert run.children[2].stats == {"planes": 2, "bytes": 2 * 6 * 128 * 4}
     for i, tick in enumerate(roots[3:]):
         assert tick.kids() == DIR_TICK
         assert set(tick.stats) == {"attempts", "releases", "extends", "t"}
@@ -166,6 +171,37 @@ def test_skip_count_against_a_hand_count(tmp_path, case):
     assert (owners[1:, :512] == 1).all() and (owners[:, 512:] < 0).all()
 
 
+@pytest.mark.parametrize("extend", [False, True], ids=["stripped", "in_use"])
+def test_device_check_counts_its_planes_and_bytes(tmp_path, monkeypatch,
+                                                  extend):
+    """The device ``lease.validate`` span counts the [T, N] planes whose
+    bounds it read and their bytes, while a trace is on; without one the
+    counters are never computed."""
+    n_cells, n_ticks = 256, 8
+    extends = np.full((n_ticks, n_cells), NO_PROPOSER, np.int32)
+    if extend:
+        extends[4] = np.arange(n_cells) % 3
+    sc = Scenario.build(n_cells=n_cells, n_acceptors=3, n_proposers=3,
+                        attempts=_scenario(n_cells, n_ticks).attempts,
+                        extends=extends)
+    n_planes = 3 if extend else 2
+    with jax.profiler.trace(str(tmp_path)):
+        LeaseArrayEngine(n_cells, n_acceptors=3, n_proposers=3,
+                         lease_ticks=6, backend="jnp").run_trace(sc)
+    (run,) = [r for r in lease_roots(tmp_path) if r.name == "lease.run_trace"]
+    host, device = run.named("lease.validate")
+    assert host.stats == {} and host.end <= run.children[1].start
+    assert device.start >= run.children[1].end
+    assert device.stats == {"planes": n_planes,
+                            "bytes": n_planes * n_ticks * n_cells * 4}
+    stats = []
+    monkeypatch.setattr(TraceAnnotation, "set_metadata",
+                        lambda self, **kw: stats.append(kw))
+    LeaseArrayEngine(n_cells, n_acceptors=3, n_proposers=3, lease_ticks=6,
+                     backend="jnp").run_trace(sc)
+    assert stats == []
+
+
 def _drive():
     eng = LeaseArrayEngine(1024, n_acceptors=3, n_proposers=3,
                            lease_ticks=6, backend="pallas")
@@ -186,8 +222,9 @@ def test_counters_only_while_a_trace_is_on(tmp_path, monkeypatch, tracing):
         with jax.profiler.trace(str(tmp_path)):
             _drive()
         # run_trace, step and the directory's two steps read the count;
-        # each directory tick also counts its renewals and its planes
-        assert len(reads) == 4 and len(stats) == 8
+        # each directory tick also counts its renewals and its planes, and
+        # run_trace its device check
+        assert len(reads) == 4 and len(stats) == 9
     else:
         _drive()
         assert reads == [] and stats == []

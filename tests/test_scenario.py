@@ -74,7 +74,7 @@ def test_bool_planes_coerce_to_int32():
 def test_run_trace_rejects_ghost_proposer_ids():
     """Regression: run_trace used to skip the proposer-id validation that
     step does — out-of-range ids silently leased cells to ghost proposers.
-    Both paths now validate in scenario.validate_proposer_ids."""
+    Both paths now refuse through scenario.check_bounds."""
     e = LeaseArrayEngine(4, n_acceptors=3, n_proposers=2)
     bad = Scenario.build(3, **GEOM)
     bad.planes["attempts"][1, 2] = 2  # == n_proposers: a ghost
