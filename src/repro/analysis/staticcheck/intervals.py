@@ -119,6 +119,10 @@ class TickConfig:
     #: proposer restart counter any tick can carry
     max_restarts: int = 0
     extend: bool = False  # thread the §6 extends plane
+    #: the restart inputs come per cell ([A, bn] / [P, bn] columns, a
+    #: placement's), not per slot; ``max_restarts`` is then the highest
+    #: count of any node
+    placed: bool = False
 
     @property
     def majority(self) -> int:
@@ -194,12 +198,15 @@ def trace_tick_core(
     corrupt: bool = False,
     restart: bool = False,
     extend: bool = False,
+    placed: bool = False,
 ):
     """``jax.make_jaxpr`` of one tick core with the protocol constants
     closed over, on tiny block shapes (intervals are shape-oblivious
     except for iota/reduction extents, which use the real A/P). Returns
     a ClosedJaxpr; cached — the expensive trace happens once per config,
-    every ``t_end`` probe of the binary search re-walks it for free."""
+    every ``t_end`` probe of the binary search re-walks it for free.
+    ``placed`` traces the restart inputs as per-cell ``[A, bn]`` /
+    ``[P, bn]`` columns (a placement's) instead of per-slot ones."""
     import jax
     import jax.numpy as jnp
 
@@ -255,9 +262,10 @@ def trace_tick_core(
 
     extra = [sds((A, 1), i32)] * 2 if corrupt else []
     if restart:
+        cols = bn if placed else 1
         extra = extra + [
-            sds((A, 1), i32), sds((A, 1), i32),
-            sds((P, 1), i32), sds((P, 1), i32),
+            sds((A, cols), i32), sds((A, cols), i32),
+            sds((P, cols), i32), sds((P, cols), i32),
         ]
     if extend:
         extra = extra + [sds((1, bn), i32)]
@@ -528,6 +536,7 @@ def _core_and_layout(cfg: TickConfig, legs: str):
         cfg.n_proposers, cfg.n_acceptors, cfg.eff_lease_q4, cfg.round_q4,
         cfg.eff_guard_q4, cfg.majority, sync=cfg.sync, legs=legs,
         corrupt=cfg.corrupt, restart=cfg.restart, extend=cfg.extend,
+        placed=cfg.placed,
     )
     if cfg.sync:
         layout = _SYNC_ARGS

@@ -195,7 +195,8 @@ def check_window_launches(
     block_n: int = 512,
     window: int = 16,
 ) -> list[Finding]:
-    """Audit both shipped window kernels at a representative geometry."""
+    """Audit both shipped window kernels at a representative geometry,
+    the delayed one in its extend and placed launches too."""
     from ...lease_array.kernel import delayed_launch_plan, sync_launch_plan
 
     A, P = n_acceptors, n_proposers
@@ -216,5 +217,14 @@ def check_window_launches(
                             block_n=block_n, window=window, extend=True),
         delayed=True, n_acceptors=A, n_proposers=P,
         what="lease_window_delayed_pallas[extend]",
+    )
+    # a placement's per-cell restart table (A == P: slot k is acceptor k
+    # and proposer k), with the extends stream of the placed cells
+    findings += check_launch_plan(
+        delayed_launch_plan(A, n_cells, A, n_ticks, block_n=block_n,
+                            window=window, extend=True, restart=True,
+                            placed=True),
+        delayed=True, n_acceptors=A, n_proposers=A,
+        what="lease_window_delayed_pallas[placed]",
     )
     return findings

@@ -176,6 +176,9 @@ def check_window_kernels(
     packed = PackedLeaseState(
         *(sds(a.shape, i32) for a in pack_state(init_state(N, A, P)))
     )
+    packed_a = PackedLeaseState(  # A == P, as a placement asks
+        *(sds(a.shape, i32) for a in pack_state(init_state(N, A, A)))
+    )
     net = NetPlaneState(*(sds(a.shape, i32) for a in init_netplane(N, A)))
     t0 = sds((), i32)
     planes = dict(
@@ -214,8 +217,25 @@ def check_window_kernels(
     )(packed, net, t0, *planes.values(), sds((T, P, A), i32),
       sds((T, N), i32))
 
+    from ...lease_array.netplane import RT_FIELDS
+
+    placed_kw = dict(kw, n_proposers=A)
+    placed_jaxpr = jax.make_jaxpr(
+        lambda p, n, t, a, r, u, pc, ac, lk, ex, tab:
+        lease_window_delayed_pallas(
+            p, n, t, a, r, u, pc, ac, lk, round_q4=4, extends=ex,
+            restart_table=tab, deaf_q4=13, **placed_kw
+        )
+    )(packed_a, net, t0, planes["attempts"], planes["releases"],
+      planes["acc_up"], sds((T, A), i32), planes["aclk"], sds((T, A, A), i32),
+      sds((T, N), i32), sds((RT_FIELDS, A, N), i32))
+
     findings = check_jaxpr_purity(
         sync_jaxpr, pallas_path=True, what="lease_window_sync_pallas"
+    )
+    findings += check_jaxpr_purity(
+        placed_jaxpr, pallas_path=True,
+        what="lease_window_delayed_pallas[placed]",
     )
     findings += check_jaxpr_purity(
         delayed_jaxpr, pallas_path=True, what="lease_window_delayed_pallas"
@@ -242,7 +262,9 @@ def check_honest_strip(
     to one that never mentioned the plane: ``ops.strip_default_planes``
     is the host-side gate ``lease_window_scan`` applies before its jit,
     so honest replays never compile (or cache-miss on) the fault
-    variants. Traces the real impl both ways and diffs the jaxprs."""
+    variants. A placement whose node rows place no restart goes with
+    them, to the same honest jaxpr. Traces the real impl both ways and
+    diffs the jaxprs."""
     import jax
     import numpy as np
 
@@ -290,4 +312,35 @@ def check_honest_strip(
             "extends plane differs from one traced without the plane — "
             "the strip no longer restores the honest computation",
         ))
+    # a placement with all-zero node rows places no restart: it goes with
+    # them, and the dispatch is the honest one (A == P for a placement)
+    placed = Scenario.build(
+        T, n_cells=N, n_acceptors=A, n_proposers=A, n_nodes=A + 2,
+        placement=(np.arange(A)[:, None] + np.arange(N)[None, :]) % (A + 2),
+        delay=np.ones((T, A), np.int32),
+    )
+    kept = ops.strip_default_planes(dict(placed.planes))
+    if set(kept) & {"placement", "acc_restart", "prop_restart"}:
+        findings.append(Finding(
+            "purity", "honest-strip", "ops.strip_default_planes",
+            "a placement without restarts survived the host-side strip; "
+            "every such replay would compile the placed variant",
+        ))
+    else:
+        state_a, net_a = init_state(N, A, A), init_netplane(N, A)
+        honest_a = ops.strip_default_planes(dict(Scenario.build(
+            T, n_cells=N, n_acceptors=A, n_proposers=A,
+            delay=np.ones((T, A), np.int32),
+        ).planes))
+        trace = lambda pl: str(jax.make_jaxpr(
+            lambda s, n_, t, p: ops._window_scan_impl(
+                s, n_, t, None, None, p, **kw
+            )
+        )(state_a, net_a, np.int32(0), pl))
+        if trace(kept) != trace(honest_a):
+            findings.append(Finding(
+                "purity", "honest-strip", "ops._window_scan_impl",
+                "a stripped placement without restarts leaves a dispatch "
+                "jaxpr that differs from the honest one",
+            ))
     return findings

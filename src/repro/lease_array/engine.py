@@ -54,7 +54,10 @@ from .ops import (
     _plane_tick,
     _window_scan_impl,
     lease_plane_tick,
+    pad_restart_table,
+    placed_restart_table,
     resolve_backend,
+    strip_default_planes,
 )
 from .ref import owner_row
 from .scenario import (
@@ -65,6 +68,7 @@ from .scenario import (
     Scenario,
     TickInputs,
     check_bounds,
+    check_placed,
     make_tick,
     plane_digest,
 )
@@ -98,13 +102,15 @@ _DEPRECATED_TRACE_PLANES = (
 def _static_pack_findings(
     t_end: int, n_proposers: int, n_acceptors: int, lease_q4: int,
     round_q4: int, guard_q4: Optional[int], max_delay: int, max_rate: int,
-    clk_slack: int, max_restarts: int = 0,
+    clk_slack: int, max_restarts: int = 0, placed: bool = False,
 ) -> tuple[str, ...]:
     """Interval-analysis twin of ``state.check_pack_budget``: walk the
     traced delayed tick core (the conservative superset of the sync one)
     and bound EVERY int32 intermediate for replays up to ``t_end``. The
     hand check budgets only ballots and lease deadlines — this one also
     sees round horizons, clock sums and any future field the core grows.
+    ``placed`` proves the core with per-cell restart columns (a
+    placement's), ``max_restarts`` then the highest count of any node.
     Cached because the same protocol config is re-proved per dispatch."""
     from ..analysis.staticcheck.intervals import (
         TickConfig,
@@ -115,7 +121,7 @@ def _static_pack_findings(
         t_end=t_end, n_proposers=n_proposers, n_acceptors=n_acceptors,
         lease_q4=lease_q4, round_q4=round_q4, guard_q4=guard_q4,
         max_delay=max_delay, max_rate=max_rate, clk_slack=clk_slack,
-        max_restarts=max_restarts,
+        max_restarts=max_restarts, placed=placed,
     )
     return tuple(str(f) for f in analyze_tick_config(cfg))
 
@@ -218,11 +224,12 @@ class SweepResult(NamedTuple):
 
 
 def _cell_sharding_specs(planes_keys):
-    """shard_map PartitionSpecs for a (state, net, t0, clk0, rst0, planes)
-    call: every state/output plane splits on its trailing cell axis;
+    """shard_map PartitionSpecs for a (state, net, t0, clk0, rst0, planes,
+    rtab) call: every state/output plane splits on its trailing cell axis;
     scenario planes split iff their registered dims carry the cell axis
     "N" (acc_up, the [T, P, A] link matrices and the clock-rate planes are
-    replicated, as are the [P]/[A] clock offsets and restart history)."""
+    replicated, as are the [P]/[A] clock offsets and restart history); a
+    placed replay's [F, A, N] restart table splits on its cell axis."""
     from jax.sharding import PartitionSpec as P
 
     from .scenario import PLANES
@@ -236,7 +243,7 @@ def _cell_sharding_specs(planes_keys):
     # per-node tuples and the None fast path (no leaves) identically; the
     # grid-step counts are summed over the devices
     return (
-        (cells, cells, P(), P(), P(), plane_specs),
+        (cells, cells, P(), P(), P(), plane_specs, P(None, None, "cells")),
         (cells, cells, cells, cells, P()),
     )
 
@@ -246,20 +253,22 @@ def _trace_fn(
     majority: int, lease_q4: int, round_q4: int, guard_q4: int, backend: str,
     sync: bool, block_n: int, window: int, n_devices: int, planes_keys: tuple,
     restart_guard: bool = True, skip_stable: bool = True,
+    max_lease_q4: Optional[int] = None,
 ):
     """The fused scenario replay, jitted; with >1 device the cell axis is
     shard_map-ed across a 1-D device mesh (cells are independent — the
     tick math never reduces across N), so a trace uses every device.
     Returns ``_window_scan_impl``'s five outputs, the grid-step counts
-    summed over the devices."""
+    summed over the devices. ``rtab`` is a placed replay's restart table
+    (None otherwise)."""
 
-    def run(state, net, t0, clk0, rst0, planes):
+    def run(state, net, t0, clk0, rst0, planes, rtab):
         *out, steps = _window_scan_impl(
             state, net, t0, clk0, rst0, planes,
             majority=majority, lease_q4=lease_q4, round_q4=round_q4,
             guard_q4=guard_q4, backend=backend, sync=sync, block_n=block_n,
             window=window, restart_guard=restart_guard,
-            skip_stable=skip_stable,
+            skip_stable=skip_stable, rtab=rtab, max_lease_q4=max_lease_q4,
         )
         if n_devices > 1:
             steps = jax.lax.psum(steps, "cells")
@@ -275,13 +284,15 @@ def _trace_fn(
             check_vma=False,
         )
 
-        def run(state, net, t0, clk0, rst0, planes):
+        def run(state, net, t0, clk0, rst0, planes, rtab):
             # an uneven split pads the cell axis with empty cells (the
             # same sentinels init_state/init_netplane and the plane
             # registry use), so every device gets an equal share
             n = state.n_cells
             state, net, planes = _pad_cell_axis(state, net, planes, n_devices)
-            *out, steps = sharded(state, net, t0, clk0, rst0, planes)
+            if rtab is not None:
+                rtab = pad_restart_table(rtab, n_devices)
+            *out, steps = sharded(state, net, t0, clk0, rst0, planes, rtab)
             if state.n_cells != n:
                 out = jax.tree.map(lambda a: a[..., :n], out)
             return (*out, steps)
@@ -318,6 +329,7 @@ def _sweep_fn(
     majority: int, lease_q4: int, round_q4: int, guard_q4: int, backend: str,
     sync: bool, block_n: int, window: int, collect: str, n_devices: int,
     restart_guard: bool = True, skip_stable: bool = True,
+    max_lease_q4: Optional[int] = None,
 ):
     """One-dispatch batched scenario replay: vmap over the stacked planes
     (state broadcast), reductions inside the jit so a summary sweep never
@@ -336,6 +348,7 @@ def _sweep_fn(
                 state, net, t0, clk0, {**cell_planes, **rest_planes},
                 majority=majority, lease_q4=lease_q4, round_q4=round_q4,
                 guard_q4=guard_q4, rst0=rst0, restart_guard=restart_guard,
+                max_lease_q4=max_lease_q4,
             )
         else:
             margins = None
@@ -344,7 +357,7 @@ def _sweep_fn(
                 majority=majority, lease_q4=lease_q4, round_q4=round_q4,
                 guard_q4=guard_q4, backend=backend, sync=sync,
                 block_n=block_n, window=window, restart_guard=restart_guard,
-                skip_stable=skip_stable,
+                skip_stable=skip_stable, max_lease_q4=max_lease_q4,
             )
         out = {
             "max_owner_count": counts.max(),
@@ -392,17 +405,6 @@ def _shard_batch(plane, n_devices: int):
     return jax.device_put(v, NamedSharding(_batch_mesh(n_devices), P("b")))
 
 
-def _only_default(plane, default, rows: int = 8) -> bool:
-    """True iff the [T, ...] ``plane`` holds nothing but ``default``. It is
-    read ``rows`` ticks at a time, so a plane in use (a [T, N] extends
-    plane of renewals) is told apart at its first block that is not,
-    without a pass over all of it before the uploads start."""
-    v = np.asarray(plane)
-    return not any(
-        (v[i:i + rows] != default).any() for i in range(0, len(v), rows)
-    )
-
-
 @functools.lru_cache(maxsize=None)
 def _bounds_fn(planes_keys: tuple):
     """Jitted planes -> int32 [K, 2]: the (min, max) of each of the
@@ -428,9 +430,42 @@ def _device_checked(planes: dict) -> tuple:
     host)."""
     return tuple(
         k for k, v in planes.items()
-        if "N" in PLANES[k].dims and PLANES[k].bounded
+        if k in PLANES and "N" in PLANES[k].dims and PLANES[k].bounded
         and np.can_cast(np.asarray(v).dtype, np.int32)
     )
+
+
+@functools.lru_cache(maxsize=None)
+def _placed_table_fn(deaf_q4: int):
+    """``ops.placed_restart_table``, jitted: a placed replay's per-cell
+    restart table from the node rows and the placement, on the device."""
+    return jax.jit(functools.partial(placed_restart_table, deaf_q4=deaf_q4))
+
+
+def _placed_counters(place, arst, prst, n_ticks: int, t0: int,
+                     deaf_q4: int, deaf0) -> dict:
+    """A placed replay's restarts, counted from its node rows ``[T, K]``
+    (None = no restarts) and ``[A, N]`` placement: ``node_restarts``,
+    the (tick, node) pairs at which a node's acceptor or proposer
+    restarts; ``restarted_cells``, the acceptor restarts of the cells'
+    replicas (a node's restarts times the replica slots placed on it);
+    ``deaf_cell_ticks``, the (tick, cell, slot) triples at which a
+    replica's acceptor is deaf (the history ``deaf0`` [K] included)."""
+    K = len(deaf0)
+    per_node = np.bincount(np.asarray(place).ravel(), minlength=K)
+    none = np.zeros((n_ticks, K), bool)
+    a = none if arst is None else np.asarray(arst) > 0
+    p = none if prst is None else np.asarray(prst) > 0
+    ticks = t0 + np.arange(n_ticks, dtype=np.int64)[:, None]
+    minted = np.where(a, 4 * ticks + deaf_q4, 0)
+    until = np.maximum(np.maximum.accumulate(minted, axis=0),
+                       np.asarray(deaf0, np.int64)[None, :])
+    deaf = (4 * ticks < until) if deaf_q4 else none
+    return {
+        "node_restarts": int((a | p).sum()),
+        "restarted_cells": int(a.sum(axis=0) @ per_node),
+        "deaf_cell_ticks": int(deaf.sum(axis=0) @ per_node),
+    }
 
 
 def _grid_counts(steps) -> dict:
@@ -455,15 +490,27 @@ class LeaseArrayEngine:
         window: int = 16,
         restart_guard: bool = True,
         skip_stable: bool = True,
+        max_lease_ticks: Optional[int] = None,
     ) -> None:
         if n_acceptors < 1 or n_proposers < 1:
             raise ValueError("need at least one acceptor and one proposer")
+        if max_lease_ticks is None:
+            max_lease_ticks = lease_ticks
+        if max_lease_ticks < lease_ticks:
+            raise ValueError(
+                f"max_lease_ticks={max_lease_ticks} is shorter than the "
+                f"lease ({lease_ticks} ticks): M bounds every lease (§2)"
+            )
         self.n_cells = n_cells
         self.n_acceptors = n_acceptors
         self.n_proposers = n_proposers
         self.majority = n_acceptors // 2 + 1
         self.lease_ticks = lease_ticks
         self.lease_q4 = lease_quarters(lease_ticks)
+        #: M, the longest lease any proposer may ask for (§2-3): a
+        #: restarted acceptor stays deaf this long on its own clock
+        self.max_lease_ticks = int(max_lease_ticks)
+        self.max_lease_q4 = lease_quarters(self.max_lease_ticks)
         self.round_ticks = round_ticks
         self.round_q4 = QUARTERS * int(round_ticks)
         #: ε, the assumed clock-drift bound (§4): proposers discount their
@@ -502,15 +549,28 @@ class LeaseArrayEngine:
         self._rc = np.zeros(n_proposers, np.int32)
         self._deaf_until = np.zeros(n_acceptors, np.int32)
         self._restart_active = False
+        # a placed deployment's (run_trace with a placement, pinned by its
+        # first restart): the [A, N] map, and per node its restart count
+        # and deaf-until in global quarter-ticks
+        self._placement = None
+        self._node_rc: Optional[np.ndarray] = None
+        self._node_du: Optional[np.ndarray] = None
 
     # -------------------------------------------------------- packing budget
-    def _max_restarts(self, prop_restart=None) -> int:
+    def _max_restarts(self, prop_restart=None, n_nodes=None) -> int:
         """The pack-budget ``max_restarts`` charge for a dispatch that may
         add ``prop_restart`` ([T, P], [B, T, P] or a single [P] row) to the
         carried counters — 0 while the engine has never seen a restart
         (the honest encoding), else at least 1 so the RESTART_SHIFT carve
-        is always charged once restart mode is on."""
+        is always charged once restart mode is on. With ``n_nodes`` (a
+        placement) ``prop_restart`` holds [T, K] node rows, added to the
+        nodes' counts: the charge is the highest count of any node."""
+        width = self.n_proposers
         rc_end = self._rc.astype(np.int64)
+        if n_nodes is not None:
+            width = n_nodes
+            rc_end = (np.zeros(n_nodes, np.int64) if self._node_rc is None
+                      else self._node_rc.astype(np.int64))
         seen = self._restart_active
         if prop_restart is not None:
             prst = np.asarray(prop_restart, np.int64)
@@ -519,11 +579,11 @@ class LeaseArrayEngine:
                     # [B, T, P] stack: each scenario replays independently,
                     # so charge the worst per-scenario total, not the sum
                     add = (
-                        prst.reshape(prst.shape[0], -1, self.n_proposers)
+                        prst.reshape(prst.shape[0], -1, width)
                         .sum(axis=1).max(axis=0)
                     )
                 else:
-                    add = prst.reshape(-1, self.n_proposers).sum(axis=0)
+                    add = prst.reshape(-1, width).sum(axis=0)
                 rc_end = rc_end + add
                 seen = seen or bool(prst.any())
         if not seen:
@@ -545,7 +605,7 @@ class LeaseArrayEngine:
 
     def _static_bound_check(
         self, t_end: int, max_delay: int = 0, max_rate: int = QUARTERS,
-        max_restarts: int = 0,
+        max_restarts: int = 0, placed: bool = False,
     ) -> None:
         """Run the leaselint interval analysis host-side before a bulk
         dispatch. Complements ``_check_pack_budget``: the hand bound is
@@ -562,7 +622,7 @@ class LeaseArrayEngine:
                 self.lease_q4, self.round_q4, self.guard_q4,
                 int(max_delay), max_rate,
                 max(0, clk_max - max_rate * self.t),
-                int(max_restarts),
+                int(max_restarts), bool(placed),
             )
         except Exception as e:
             raise RuntimeError(
@@ -616,10 +676,32 @@ class LeaseArrayEngine:
             [np.zeros((1, self.n_acceptors), np.int64),
              np.cumsum(rate, axis=0)[:-1]]
         )
-        minted = np.where(arst > 0, aclk + self.lease_q4, 0)
+        minted = np.where(arst > 0, aclk + self.max_lease_q4, 0)
         self._deaf_until = np.maximum(
             self._deaf_until, minted.max(axis=0, initial=0)
         ).astype(np.int32)
+
+    def _advance_node_restarts(self, arst, prst, t0: int) -> None:
+        """Fold a placed dispatch's [T, K] node rows (None = none) into
+        the nodes' carried restart counts and deaf-until readings."""
+        for rows in (arst, prst):
+            if rows is not None:
+                ticks = t0 + np.arange(len(rows), dtype=np.int64)
+                break
+        else:
+            return
+        if prst is not None:
+            self._node_rc = (
+                self._node_rc + np.asarray(prst, np.int64).sum(axis=0)
+            ).astype(np.int32)
+        if arst is not None:
+            minted = np.where(
+                np.asarray(arst) > 0, 4 * ticks[:, None] + self.max_lease_q4,
+                0,
+            )
+            self._node_du = np.maximum(
+                self._node_du, minted.max(axis=0, initial=0)
+            ).astype(np.int32)
 
     def _advance_clocks(self, prop_rate, acc_rate) -> None:
         """Accumulate the scenario's rate planes ([T, P]/[T, A] or one
@@ -660,6 +742,11 @@ class LeaseArrayEngine:
         """
         with span("lease.step") as step_span:
             with span("lease.validate"):
+                if self._placement is not None:
+                    raise ValueError(
+                        "this engine replays a placed deployment; a "
+                        "placement is replayed whole, by run_trace"
+                    )
                 if tick is not None and not isinstance(tick, TickInputs):
                     if attempt is not None:
                         raise TypeError(
@@ -731,6 +818,7 @@ class LeaseArrayEngine:
                     restart_guard=self.restart_guard, backend=self.backend,
                     sync=not self._netplane_active, window=self.window,
                     skip_stable=self.skip_stable,
+                    max_lease_q4=self.max_lease_q4,
                 )
                 self.t += 1
                 if self._restart_active:
@@ -751,8 +839,9 @@ class LeaseArrayEngine:
     def _coerce_scenario(self, scenario, releases, acc_up, delay, drop):
         """The call's Scenario, checked on the host but for the bounds of
         its [T, N] planes: ``run_trace`` reads those on the device once
-        they are uploaded (``_device_checked``). The legacy raw-array form
-        builds its Scenario, which checks every plane on the host."""
+        they are uploaded (``_device_checked``), and checks a placement in
+        its ``lease.placement`` span. The legacy raw-array form builds its
+        Scenario, which checks every plane on the host."""
         if not isinstance(scenario, Scenario):
             scenario = Scenario.build(
                 n_cells=self.n_cells, n_acceptors=self.n_acceptors,
@@ -764,7 +853,7 @@ class LeaseArrayEngine:
             scenario.validate_for(
                 n_cells=self.n_cells, n_acceptors=self.n_acceptors,
                 n_proposers=self.n_proposers,
-                skip_bounds=_device_checked(scenario.planes),
+                skip_bounds=_device_checked(scenario.planes) + ("placement",),
             )
         return scenario
 
@@ -834,18 +923,18 @@ class LeaseArrayEngine:
                 # tick variants (bit-identical jaxpr, zero extra uploads);
                 # once restart mode is pinned, rst0 (not the planes) keeps
                 # it on across quiet dispatches. A stripped plane is valid:
-                # it holds nothing but its default
-                kept = {
-                    k: v for k, v in scenario.planes.items()
-                    if not (
-                        k in CORRUPTION_PLANES + RESTART_PLANES + EXTEND_PLANES
-                        and _only_default(v, PLANES[k].default)
-                    )
-                }
+                # it holds nothing but its default. A placement with no
+                # restart and no placed history goes with them
+                kept = strip_default_planes(
+                    scenario.planes,
+                    restart_history=self._placement is not None,
+                )
                 restarted = any(k in kept for k in RESTART_PLANES)
+                placed = "placement" in kept
+                self._check_placed_engine(placed, kept.get("placement"))
                 sync = self._pick_model(
                     netplane,
-                    scenario.delayed or restarted or any(
+                    scenario.delayed or restarted or placed or any(
                         k in kept for k in CORRUPTION_PLANES + EXTEND_PLANES
                     ),
                 )
@@ -858,9 +947,35 @@ class LeaseArrayEngine:
                     int(np.asarray(scenario.prop_rate).max(initial=0)),
                     int(np.asarray(scenario.acc_rate).max(initial=0)),
                 )
-                mr = self._max_restarts(scenario.prop_restart)
+                n_nodes = scenario.n_nodes if placed else None
+                mr = self._max_restarts(scenario.prop_restart, n_nodes)
                 self._check_pack_budget(self.t + T, dmax, rmax, mr)
-                self._static_bound_check(self.t + T, dmax, rmax, mr)
+                self._static_bound_check(self.t + T, dmax, rmax, mr, placed)
+            rtab = node_rows = None
+            if placed:
+                with span("lease.placement"):
+                    # the map's host checks, its upload and the node rows',
+                    # and the per-cell restart table derived on the device
+                    place = kept.pop("placement")
+                    node_rows = tuple(kept.pop(k, None) for k in RESTART_PLANES)
+                    check_placed(
+                        scenario.planes, n_nodes, self.n_acceptors,
+                        self.n_proposers, "Scenario",
+                    )
+                    hist = (
+                        (jnp.zeros(n_nodes, jnp.int32),) * 2
+                        if self._node_rc is None
+                        else (jnp.asarray(self._node_rc),
+                              jnp.asarray(self._node_du))
+                    )
+                    rtab = _placed_table_fn(self._deaf_q4)(
+                        hist,
+                        *(None if r is None else jnp.asarray(r)
+                          for r in node_rows),
+                        jnp.asarray(place), jnp.int32(self.t),
+                    )
+                    if tracing:  # the span ends with the table made
+                        jax.block_until_ready(rtab)
             with span("lease.upload"):
                 planes = {k: jnp.asarray(v) for k, v in kept.items()}
                 if tracing:  # the span ends with the planes on the device
@@ -888,18 +1003,26 @@ class LeaseArrayEngine:
                 if restarted:
                     # pins the restart ballot encoding
                     self._restart_active = True
+                t0 = self.t
+                if placed and self._node_rc is None:
+                    self._placement = place
+                    self._node_rc = np.zeros(n_nodes, np.int32)
+                    self._node_du = np.zeros(n_nodes, np.int32)
+                deaf0 = self._node_du
                 fn = _trace_fn(
                     self.majority, self.lease_q4, self.round_q4, self.guard_q4,
                     self.backend, sync, 512, self.window, len(jax.devices()),
                     tuple(planes),
-                    self.restart_guard, self.skip_stable,
+                    self.restart_guard, self.skip_stable, self.max_lease_q4,
                 )
                 self.state, self.net, owners, counts, steps = fn(
-                    self.state, self.net, jnp.int32(self.t), self._clk0(),
-                    self._rst0(), planes
+                    self.state, self.net, jnp.int32(t0), self._clk0(),
+                    None if placed else self._rst0(), planes, rtab,
                 )
                 self.t += int(T)
-                if self._restart_active:
+                if placed:
+                    self._advance_node_restarts(*node_rows, t0)
+                elif self._restart_active:
                     self._advance_restarts(
                         scenario.acc_restart, scenario.prop_restart,
                         scenario.acc_rate,
@@ -912,7 +1035,48 @@ class LeaseArrayEngine:
                 owners, counts = np.asarray(owners), np.asarray(counts)
             if tracing:
                 run_span.set_metadata(**_grid_counts(steps))
+                if placed:
+                    run_span.set_metadata(**_placed_counters(
+                        place, *node_rows, T, t0, self._deaf_q4, deaf0,
+                    ))
             return owners, counts
+
+    @property
+    def _deaf_q4(self) -> int:
+        """The post-restart deaf window, M in quarter-ticks of the
+        restarted acceptor's clock (0 under ``restart_guard=False``)."""
+        return self.max_lease_q4 if self.restart_guard else 0
+
+    def _check_placed_engine(self, placed: bool, place) -> None:
+        """Refuse a dispatch that would mix placed and per-slot restart
+        histories: a placed engine (pinned by its first placed dispatch)
+        takes only its own placement, and a placement needs an engine
+        with no per-slot restart history whose clocks run at the rate-1
+        step."""
+        if self._placement is not None:
+            if not placed:
+                raise ValueError(
+                    "this engine replays a placed deployment; pass the "
+                    "scenario's placement with every run_trace"
+                )
+            if place is not self._placement and not np.array_equal(
+                place, self._placement
+            ):
+                raise ValueError(
+                    "this engine replays a placed deployment with another "
+                    "placement; a placement cannot change mid-trace"
+                )
+        elif placed:
+            if self._restart_active:
+                raise ValueError(
+                    "a placement needs an engine without per-slot restart "
+                    "history; replay the placed deployment on a fresh engine"
+                )
+            if self._clk0() is not None:
+                raise ValueError(
+                    "a placement needs clocks at the rate-1 reading; this "
+                    "engine replayed drifted clock planes"
+                )
 
     # ----------------------------------------------------------- the sweep
     def sweep(
@@ -950,6 +1114,11 @@ class LeaseArrayEngine:
         """
         if collect not in ("summary", "owners", "margins"):
             raise ValueError(f"unknown collect mode {collect!r}")
+        if self._placement is not None:
+            raise ValueError(
+                "this engine replays a placed deployment; sweep it with "
+                "run_trace on fresh engines"
+            )
         if isinstance(scenarios, (list, tuple)):
             if not scenarios:
                 raise ValueError("sweep needs at least one scenario")
@@ -961,6 +1130,11 @@ class LeaseArrayEngine:
             stacked = Scenario.stack(scenarios)
         else:
             stacked = scenarios
+        if "placement" in stacked.planes:
+            raise ValueError(
+                "a sweep takes no placement: replay a placed deployment "
+                "with run_trace"
+            )
         # one host read per fault plane (the delay plane feeds both the
         # model choice and the pack-budget check; don't pull it twice)
         dmax = int(np.asarray(stacked.planes["delay"]).max(initial=0))
@@ -1045,7 +1219,7 @@ class LeaseArrayEngine:
             self.majority, self.lease_q4, self.round_q4, self.guard_q4,
             self.backend if backend is None else resolve_backend(backend),
             sync, 512, self.window, collect, n_dev,
-            self.restart_guard, self.skip_stable,
+            self.restart_guard, self.skip_stable, self.max_lease_q4,
         )
         out = fn(
             self.state, self.net, jnp.int32(self.t), self._clk0(),

@@ -52,7 +52,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .netplane import NetPlaneState, delayed_tick_math, legs_select
+from .netplane import (
+    RT_ACC,
+    RT_FIELDS,
+    RT_PROP,
+    NetPlaneState,
+    delayed_tick_math,
+    legs_select,
+    placed_restart_columns,
+)
+from .state import MAX_RESTARTS
 from .ref import sync_tick_math
 from .state import PACK_SHIFT, PackedLeaseState, clock_select
 
@@ -127,18 +136,27 @@ def _window_geometry(n_cells: int, n_ticks: int, block_n: int, window: int):
     return block_n, tw, n_windows
 
 
+def _table_spec(fields: int, rows: int, block_n: int):
+    """A per-cell [fields, rows, N] table read whole by each cell block:
+    resident across the window axis like the state planes."""
+    return pl.BlockSpec((fields, rows, block_n), lambda i, w: (0, 0, i))
+
+
 def _launch_plan(
     rows, n_acceptors: int, n_cells: int, n_proposers: int, n_ticks: int,
     block_n: int, window: int, bcast_rows: tuple[tuple[int, int], ...],
     n_cell_planes: int = 2, skip_row: bool = False,
+    table: tuple[int, int] | None = None,
 ) -> LaunchPlan:
     """Shared plan builder: ``rows`` describes the resident state planes
     (None -> A rows), ``n_cell_planes`` how many [T, N] cell-plane streams
     follow them (attempts/releases, plus the §6 extends stream), and
     ``bcast_rows`` the trailing cell-independent streams as (rows, cols)
-    pairs. ``skip_row`` appends a resident [1, N] output after the owners
-    and counts: each cell block's count of windows that took the
-    quiescent path."""
+    pairs. ``table`` (fields, rows) appends a resident per-cell
+    [fields, rows, N] input last (a placed replay's restart table).
+    ``skip_row`` appends a resident [1, N] output after the owners and
+    counts: each cell block's count of windows that took the quiescent
+    path."""
     A, N, T = n_acceptors, n_cells, n_ticks
     block_n, tw, n_windows = _window_geometry(N, T, block_n, window)
     grid = (N // block_n, n_windows)
@@ -157,6 +175,9 @@ def _launch_plan(
         ((2,), *state_shapes, *(cell_shape,) * n_cell_planes)
         + tuple((n_windows, tw, r, c) for r, c in bcast_rows)
     )
+    if table is not None:
+        in_specs += (_table_spec(*table, block_n),)
+        in_shapes += ((*table, N),)
     skip_specs = tuple(_state_specs((1,), A, block_n)) if skip_row else ()
     skip_shapes = ((1, N),) if skip_row else ()
     return LaunchPlan(
@@ -187,7 +208,7 @@ def sync_launch_plan(
 def delayed_launch_plan(
     n_acceptors: int, n_cells: int, n_proposers: int, n_ticks: int,
     *, block_n: int = 512, window: int = 16, corrupt: bool = False,
-    restart: bool = False, extend: bool = False,
+    restart: bool = False, extend: bool = False, placed: bool = False,
 ) -> LaunchPlan:
     """Launch geometry of ``lease_window_delayed_pallas``: lease + netplane
     state, the same streams as sync, plus the fused [P, A] link matrices.
@@ -197,17 +218,21 @@ def delayed_launch_plan(
     equivocation masks) to the streamed planes; ``restart`` appends the
     four crash/restart columns (acceptor restart + deaf-window masks
     [A, 1], proposer restart + running restart counters [P, 1]) — the
-    honest launch is geometry-identical to the pre-falsifier kernel. The
-    outputs end with the resident [1, N] skip-count row."""
+    honest launch is geometry-identical to the pre-falsifier kernel.
+    ``placed`` (a placement's per-cell restarts) reads the restart inputs
+    from a resident per-cell [RT_FIELDS, A, N] restart table instead of
+    the four streamed columns. The outputs end with the resident [1, N]
+    skip-count row."""
     A, P = n_acceptors, n_proposers
     bcast = ((A, 1), (P, 1), (A, 1), (P, A))
     if corrupt:
         bcast += ((A, 1), (A, 1))
-    if restart:
+    if restart and not placed:
         bcast += ((A, 1), (A, 1), (P, 1), (P, 1))
     return _launch_plan(
         _LEASE_ROWS + _NET_ROWS, A, n_cells, P, n_ticks, block_n, window,
         bcast_rows=bcast, n_cell_planes=3 if extend else 2, skip_row=True,
+        table=(RT_FIELDS, A) if placed else None,
     )
 
 
@@ -261,7 +286,8 @@ def _sync_window_kernel(
 
 def _quiescent(
     st_refs, att_ref, rel_ref, ext_ref, pclk_ref, aclk_ref,
-    stale_ref, equiv_ref, rst_refs, tw: int,
+    stale_ref, equiv_ref, rst_refs, tw: int, tab_ref=None, t_base=None,
+    n_ticks=None,
 ):
     """True iff this (cell block, window) pair provably cannot change the
     resident state: no message in flight, no open round, no scheduled
@@ -288,6 +314,13 @@ def _quiescent(
     if rst_refs is not None:
         arst_ref, _, prst_ref, _ = rst_refs
         quiet &= jnp.all(arst_ref[...] == 0) & jnp.all(prst_ref[...] == 0)
+    if tab_ref is not None:
+        # a placed replay: no cell of the block restarts inside the window
+        t_end = t_base + n_ticks
+        for f in (*range(RT_ACC, RT_ACC + MAX_RESTARTS),
+                  *range(RT_PROP, RT_PROP + MAX_RESTARTS)):
+            ticks = tab_ref[f]
+            quiet &= jnp.all((ticks < t_base) | (ticks >= t_end))
     # leases must outlive the window on their holder's LOCAL clock: clocks
     # only advance, so the slab's last reading is the window's worst case
     own_id = st_refs[_OWN_ID][...]
@@ -309,12 +342,14 @@ def _delayed_window_kernel(
     *refs,
     majority: int, lease_q4: int, round_q4: int, guard_q4: int,
     n_proposers: int, tw: int, corrupt: bool = False, restart: bool = False,
-    extend: bool = False, skip_stable: bool = True,
+    extend: bool = False, skip_stable: bool = True, placed: bool = False,
+    deaf_q4: int = 0,
 ):
     n_state = N_LEASE + N_NET
     n_cell = 3 if extend else 2
     n_in = (
-        n_state + n_cell + 4 + (2 if corrupt else 0) + (4 if restart else 0)
+        n_state + n_cell + 4 + (2 if corrupt else 0)
+        + (4 if restart and not placed else 0) + (1 if placed else 0)
     )
     ins, outs = refs[:n_in], refs[n_in:]
     att_ref, rel_ref = ins[n_state:n_state + 2]
@@ -326,7 +361,8 @@ def _delayed_window_kernel(
     if corrupt:
         stale_ref, equiv_ref = ins[extra:extra + 2]
         extra += 2
-    rst_refs = ins[extra:extra + 4] if restart else None
+    rst_refs = ins[extra:extra + 4] if restart and not placed else None
+    tab_ref = ins[extra] if placed else None
     st_refs = outs[:n_state]
     own_ref, cnt_ref, skip_ref = outs[n_state:n_state + 3]
     _init_resident(pl.program_id(1), ins[:n_state], st_refs)
@@ -346,7 +382,15 @@ def _delayed_window_kernel(
         )
         if extend:
             adv["extend"] = ext_ref[tau]
-        if restart:
+        if placed:
+            arst, deaf, prst, rc = placed_restart_columns(
+                tab_ref, t_base + tau, deaf_q4=deaf_q4
+            )
+            adv.update(
+                acc_restart=arst, acc_deaf=deaf, prop_restart=prst,
+                prop_rc=rc,
+            )
+        elif restart:
             arst_ref, deaf_ref, prst_ref, rc_ref = rst_refs
             adv.update(
                 acc_restart=arst_ref[tau], acc_deaf=deaf_ref[tau],
@@ -377,7 +421,7 @@ def _delayed_window_kernel(
 
     skip = _quiescent(
         st_refs, att_ref, rel_ref, ext_ref, pclk_ref, aclk_ref,
-        stale_ref, equiv_ref, rst_refs, tw,
+        stale_ref, equiv_ref, rst_refs, tw, tab_ref, t_base, n_ticks,
     )
 
     @pl.when(skip)
@@ -493,6 +537,8 @@ def lease_window_delayed_pallas(
     acc_deaf=None,      # [T, A] post-restart deaf-window mask
     prop_restart=None,  # [T, P] proposer crash+restart mask
     prop_rc=None,       # [T, P] running per-proposer restart counters
+    restart_table=None,  # [RT_FIELDS, A, N] a placed replay's restarts
+    deaf_q4: int = 0,   # its deaf window (0 = restart_guard=False)
 ) -> tuple[PackedLeaseState, NetPlaneState, jax.Array, jax.Array, jax.Array]:
     """Replay T delayed-model ticks in ONE kernel launch (state AND the
     in-flight netplane stay VMEM-resident across windows). Returns
@@ -504,7 +550,10 @@ def lease_window_delayed_pallas(
     mask streams both as extra [A, 1] broadcast columns and compiles the
     corrupted tick body; passing any restart input streams all four
     crash/restart columns likewise; the honest launch is unchanged.
-    ``skip_stable`` compiles the per-(block, window) quiescence check:
+    ``restart_table`` (with A == P) replaces the four columns by the
+    per-cell restart table of a placed replay (``netplane.
+    placed_restart_columns`` derives each tick's columns from it, with
+    the deaf window ``deaf_q4``). ``skip_stable`` compiles the per-(block, window) quiescence check:
     windows whose cell block provably cannot change (no traffic, no
     events, no expiry in reach) collapse to owner-row broadcasts instead
     of running the tick loop — bit-identical results, a fraction of the
@@ -514,12 +563,20 @@ def lease_window_delayed_pallas(
     T = attempts.shape[0]
     extend = extends is not None
     corrupt = stale is not None or equiv is not None
-    restart = any(
+    placed = restart_table is not None
+    restart = placed or any(
         x is not None for x in (acc_restart, acc_deaf, prop_restart, prop_rc)
     )
+    if placed and (A != P or any(
+        x is not None for x in (acc_restart, acc_deaf, prop_restart, prop_rc)
+    )):
+        raise ValueError(
+            "a restart table takes the place of the four restart columns "
+            "and needs A == P"
+        )
     plan = delayed_launch_plan(
         A, N, P, T, block_n=block_n, window=window, corrupt=corrupt,
-        restart=restart, extend=extend,
+        restart=restart, extend=extend, placed=placed,
     )
     tw, n_windows = plan.tw, plan.n_windows
 
@@ -528,7 +585,8 @@ def lease_window_delayed_pallas(
         majority=majority, lease_q4=lease_q4, round_q4=round_q4,
         guard_q4=lease_q4 if guard_q4 is None else guard_q4,
         n_proposers=P, tw=tw, corrupt=corrupt, restart=restart,
-        extend=extend, skip_stable=skip_stable,
+        extend=extend, skip_stable=skip_stable, placed=placed,
+        deaf_q4=deaf_q4,
     )
     row_plane = lambda p: _windowed(
         jnp.asarray(p, jnp.int32), n_windows, tw, 1, N
@@ -574,8 +632,9 @@ def lease_window_delayed_pallas(
                 col_plane(jnp.zeros((T, P), jnp.int32) if prop_rc is None
                           else prop_rc, P),
             )
-            if restart else ()
+            if restart and not placed else ()
         ),
+        *((jnp.asarray(restart_table, jnp.int32),) if placed else ()),
     )
     n_state = N_LEASE + N_NET
     new_packed = PackedLeaseState(*outs[:N_LEASE])
@@ -593,13 +652,15 @@ def lease_window_delayed_pallas(
 
 def delayed_kernel_args(
     n_acceptors: int, n_cells: int, n_proposers: int, n_ticks: int, *,
-    extend: bool = False, restart: bool = False, sharding=None,
+    extend: bool = False, restart: bool = False, placed: bool = False,
+    sharding=None,
 ) -> tuple[tuple, dict]:
     """Abstract int32 arguments of :func:`lease_window_delayed_pallas` for
     an ahead-of-time compile without data: ``(args, streams)``, the
     positional state and planes and the keyword streams of the ``extends``
-    and restart variants, as ``jax.jit(f).lower(args, streams)`` takes
-    them. ``sharding`` places every argument (e.g. on a described chip)."""
+    and restart variants (``placed``: the restart table), as
+    ``jax.jit(f).lower(args, streams)`` takes them. ``sharding`` places
+    every argument (e.g. on a described chip)."""
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
     A, N, P, T = n_acceptors, n_cells, n_proposers, n_ticks
     args = (
@@ -609,7 +670,9 @@ def delayed_kernel_args(
         sds(T, P, A),
     )
     streams = {"extends": sds(T, N)} if extend else {}
-    if restart:
+    if placed:
+        streams["restart_table"] = sds(RT_FIELDS, A, N)
+    elif restart:
         streams.update(
             acc_restart=sds(T, A), acc_deaf=sds(T, A),
             prop_restart=sds(T, P), prop_rc=sds(T, P),

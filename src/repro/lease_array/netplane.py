@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from .state import (
+    MAX_RESTARTS,
     NO_PROPOSER,
     PACK_MASK,
     PACK_SHIFT,
@@ -210,6 +211,54 @@ def legs_gather(link, prop):
         idx = jnp.broadcast_to(idx, (A,) + idx.shape[1:])
     v = jnp.take_along_axis(link.T, idx, axis=1)
     return QUARTERS * (v >> 1), (v & 1) > 0
+
+
+# ---------------------------------------------------------------------------
+# placed restarts: each cell's replicas on nodes of a cluster (scenario.py's
+# ``placement``). A placed replay carries a per-cell restart table
+# ``[RT_FIELDS, A, N]`` (``ops.placed_restart_table``, gathered once per
+# dispatch from the node rows through the placement): per replica slot, the
+# first MAX_RESTARTS ticks its node's acceptor and its node's proposer
+# restart in the dispatch (NO_TICK where fewer), and the node's restart
+# history at the dispatch's start. Each tick derives the four restart
+# columns from it (``placed_restart_columns``) — per cell, where the shared
+# planes give one column per slot.
+# ---------------------------------------------------------------------------
+NO_TICK = 1 << 24  # "no restart": past any tick the packed layout reaches
+RT_ACC = 0                      # fields [0, R): acceptor restart ticks
+RT_PROP = MAX_RESTARTS          # fields [R, 2R): proposer restart ticks
+RT_DEAF0 = 2 * MAX_RESTARTS     # deaf-until at the start (global q4; 0 = none)
+RT_RC0 = 2 * MAX_RESTARTS + 1   # restart counter at the start
+RT_FIELDS = 2 * MAX_RESTARTS + 2
+
+
+def placed_restart_columns(tab, t, *, deaf_q4: int):
+    """The restart inputs of ``delayed_tick_math`` at tick ``t`` for a
+    placed replay, from the per-cell restart table ``tab`` (an array or a
+    kernel ref, indexed by field: ``tab[f]`` is ``[A, bn]``). Returns
+    ``(acc_restart, acc_deaf, prop_restart, prop_rc)``, each ``[A, bn]``
+    int32 (A == P: slot ``k`` is acceptor ``k`` and proposer ``k``).
+
+    A node restarting at tick ``s`` blanks the slot's acceptor at ``s``
+    and keeps it deaf while ``4 (t - s) < deaf_q4`` — the maximal lease
+    span on the acceptor's own clock, which with a placement ticks at the
+    rate-1 step, so the window is the shared planes' cummax rule in
+    global quarter-ticks; ``deaf_q4 = 0`` is the ``restart_guard=False``
+    control. The proposer's counter is its history plus its restarts so
+    far, inclusive (a proposer restarting at ``t`` attempts at ``t`` with
+    the bumped counter, as ``ops._restart_planes`` counts)."""
+    t4 = QUARTERS * t
+    arst = prst = None
+    deaf = t4 < tab[RT_DEAF0]
+    rc = tab[RT_RC0]
+    for j in range(MAX_RESTARTS):
+        acc, prop = tab[RT_ACC + j], tab[RT_PROP + j]
+        arst = (acc == t) if arst is None else arst | (acc == t)
+        prst = (prop == t) if prst is None else prst | (prop == t)
+        deaf = deaf | ((acc <= t) & (QUARTERS * (t - acc) < deaf_q4))
+        rc = rc + (prop <= t).astype(jnp.int32)
+    i32 = lambda m: m.astype(jnp.int32)
+    return i32(arst), i32(deaf), i32(prst), rc
 
 
 def delayed_tick_math(

@@ -39,10 +39,15 @@ import numpy as np
 
 from .kernel import lease_window_delayed_pallas, lease_window_sync_pallas
 from .netplane import (
+    NO_TICK,
     R_PROPOSING,
+    RT_DEAF0,
+    RT_FIELDS,
+    RT_RC0,
     NetPlaneState,
     delayed_tick_math,
     pack_link,
+    placed_restart_columns,
 )
 from .ref import link_matrix, sync_tick_math
 from .scenario import (
@@ -54,6 +59,7 @@ from .scenario import (
     make_tick,
 )
 from .state import (
+    MAX_RESTARTS,
     NO_PROPOSER,
     PACK_MASK,
     PACK_SHIFT,
@@ -127,7 +133,8 @@ def _local_clock_planes(t0, T: int, clk0, planes: dict, n_proposers: int,
     )
 
 
-def _restart_planes(rst0, arst, prst, aclk, lease_q4: int, guard: bool):
+def _restart_planes(rst0, arst, prst, aclk, max_lease_q4: int,
+                    guard: bool):
     """Absolute per-tick crash/restart planes, precomputed like the clock
     planes so restart state needs NO scan carry:
 
@@ -137,8 +144,9 @@ def _restart_planes(rst0, arst, prst, aclk, lease_q4: int, guard: bool):
                            persisted-counter bump);
       ``deaf [T, A]``      1 while the acceptor is inside its post-restart
                            deaf window: its local clock has not yet
-                           advanced a maximal lease span (``lease_q4``
-                           local quarter-ticks — M on ITS clock domain)
+                           advanced a maximal lease span
+                           (``max_lease_q4`` local quarter-ticks — M on
+                           ITS clock domain)
                            past the latest restart (a running cummax of
                            restart-minted horizons vs ``aclk``);
       ``deaf_rem [T, A]``  local quarter-ticks of deaf window remaining
@@ -154,7 +162,9 @@ def _restart_planes(rst0, arst, prst, aclk, lease_q4: int, guard: bool):
     rc = jnp.cumsum(jnp.asarray(prst, jnp.int32), axis=0)
     if rc0 is not None:
         rc = rc + jnp.asarray(rc0, jnp.int32)[None, :]
-    minted = jnp.where(jnp.asarray(arst, jnp.int32) > 0, aclk + lease_q4, 0)
+    minted = jnp.where(
+        jnp.asarray(arst, jnp.int32) > 0, aclk + max_lease_q4, 0
+    )
     du = jax.lax.cummax(minted, axis=0)
     if du0 is not None:
         du = jnp.maximum(du, jnp.asarray(du0, jnp.int32)[None, :])
@@ -162,6 +172,66 @@ def _restart_planes(rst0, arst, prst, aclk, lease_q4: int, guard: bool):
     if not guard:
         deaf_rem = jnp.zeros_like(deaf_rem)
     return rc, (deaf_rem > 0).astype(jnp.int32), deaf_rem
+
+
+def placed_restart_table(rst0, arst, prst, place, t0, *, deaf_q4: int):
+    """The per-cell restart table ``[RT_FIELDS, A, N]`` of a placed
+    replay (``netplane.placed_restart_columns`` reads it), derived on the
+    device from the ``[T, K]`` node rows ``arst``/``prst`` (None = no
+    restarts) and the ``[A, N]`` placement: each node's first
+    MAX_RESTARTS acceptor and proposer restart ticks (absolute, from
+    ``t0``; NO_TICK where fewer) and its history at ``t0``, ``rst0`` =
+    (restart count [K], deaf-until [K] in global quarter-ticks; None =
+    fresh), gathered once per replica slot of every cell. Nothing
+    ``[T, N]``-sized is formed. ``deaf_q4 = 0`` (the unguarded control)
+    drops the deaf history with the deaf window."""
+    place = jnp.asarray(place, jnp.int32)
+    rows = [r for r in (arst, prst) if r is not None]
+    if rows:
+        T, K = jnp.shape(rows[0])
+    else:
+        T, K = 0, jnp.shape(rst0[0])[0]
+    ticks = jnp.asarray(t0, jnp.int32) + jnp.arange(T, dtype=jnp.int32)
+
+    def firsts(node_rows):
+        if node_rows is None:
+            return [jnp.full((K,), NO_TICK, jnp.int32)] * MAX_RESTARTS
+        hit = jnp.asarray(node_rows, jnp.int32) > 0
+        rank = jnp.cumsum(hit.astype(jnp.int32), axis=0)
+        return [
+            jnp.min(
+                jnp.where(hit & (rank == j + 1), ticks[:, None], NO_TICK),
+                axis=0, initial=NO_TICK,
+            )
+            for j in range(MAX_RESTARTS)
+        ]
+
+    zero = jnp.zeros((K,), jnp.int32)
+    rc0, du0 = (zero, zero) if rst0 is None else (
+        jnp.asarray(rst0[0], jnp.int32), jnp.asarray(rst0[1], jnp.int32)
+    )
+    if deaf_q4 == 0:
+        du0 = zero
+    fields = firsts(arst) + firsts(prst)
+    fields.insert(RT_DEAF0, du0)
+    fields.insert(RT_RC0, rc0)
+    # one gather per field from its [K] row: a gather of [F, K] columns
+    # pads each gathered index to a full lane tile on the TPU (16x the
+    # table's bytes in temporaries at a fleet's size)
+    return jnp.stack([
+        jnp.take(row, place, mode="clip") for row in fields
+    ])                                                           # [F, A, N]
+
+
+def pad_restart_table(tab, multiple: int):
+    """Append empty cells to a restart table until its cell axis divides
+    by ``multiple``: no restarts, no history."""
+    pad = (-tab.shape[-1]) % multiple
+    if pad == 0:
+        return tab
+    fill = jnp.full((RT_FIELDS, tab.shape[1], pad), NO_TICK, jnp.int32)
+    fill = fill.at[RT_DEAF0].set(0).at[RT_RC0].set(0)
+    return jnp.concatenate([tab, fill], axis=-1)
 
 
 def _pad_cells(arrays, multiple: int, pad_values):
@@ -223,14 +293,20 @@ def _window_scan_impl(
     window: int,
     restart_guard: bool = True,
     skip_stable: bool = True,
+    rtab=None,
+    max_lease_q4: int | None = None,
 ):
     """Shared unjitted body of the fused scan (also vmapped by
     ``engine.sweep``). ``planes`` is the Scenario plane dict ([T, ...]
     arrays); ``clk0`` the (prop [P], acc [A]) local-clock offsets at
     ``t0`` (None = the rate-1 reading ``4·t0``); ``rst0`` the
     (restart-counter [P], deaf-until [A]) restart history at ``t0``
-    (None = fresh). Returns (state', net', owners [T, N], counts [T, N],
-    steps [2]): ``steps`` counts the window kernel's grid steps that took
+    (None = fresh). ``rtab`` is a placed replay's per-cell restart table
+    (``placed_restart_table``; ``planes`` then holds no restart plane and
+    ``rst0`` is unused: the table holds the history). Returns
+    (state', net', owners [T, N], counts [T, N],
+    steps [2]). ``max_lease_q4`` is M, the post-restart deaf window
+    (None = ``lease_q4``). ``steps`` counts the window kernel's grid steps that took
     the quiescent path, then all of its grid steps (both 0 where no
     delayed window kernel ran: the jnp scan and the synchronous kernel)."""
     backend = resolve_backend(backend)
@@ -276,13 +352,25 @@ def _window_scan_impl(
     # switches mid-trace
     arst = planes.get("acc_restart")
     prst = planes.get("prop_restart")
-    restart = arst is not None or prst is not None or rst0 is not None
-    if restart:
-        if sync:
+    placed = rtab is not None
+    restart = (
+        placed or arst is not None or prst is not None or rst0 is not None
+    )
+    if restart and sync:
+        raise ValueError(
+            "restart planes (acc_restart/prop_restart) need the "
+            "delayed model; the synchronous tick cannot honor them"
+        )
+    if max_lease_q4 is None:
+        max_lease_q4 = lease_q4
+    deaf_q4 = max_lease_q4 if restart_guard else 0
+    if placed:
+        if arst is not None or prst is not None:
             raise ValueError(
-                "restart planes (acc_restart/prop_restart) need the "
-                "delayed model; the synchronous tick cannot honor them"
+                "a placed replay's restarts come in its restart table, "
+                "not in restart planes"
             )
+    elif restart:
         arst = (
             jnp.zeros((T, A), jnp.int32) if arst is None
             else jnp.asarray(arst, jnp.int32)
@@ -292,7 +380,7 @@ def _window_scan_impl(
             else jnp.asarray(prst, jnp.int32)
         )
         rc, deaf, _ = _restart_planes(
-            rst0, arst, prst, aclk, lease_q4, restart_guard
+            rst0, arst, prst, aclk, max_lease_q4, restart_guard
         )
     if not sync:
         link = pack_link(planes["delay"], planes["drop"])  # [T, P, A]
@@ -327,7 +415,15 @@ def _window_scan_impl(
                 if corrupt:
                     adv.update(stale=xs[i][:, None], equiv=xs[i + 1][:, None])
                     i += 2
-                if restart:
+                if placed:
+                    arst_t, deaf_t, prst_t, rc_t = placed_restart_columns(
+                        rtab, t, deaf_q4=deaf_q4
+                    )
+                    adv.update(
+                        acc_restart=arst_t, acc_deaf=deaf_t,
+                        prop_restart=prst_t, prop_rc=rc_t,
+                    )
+                elif restart:
                     adv.update(
                         acc_restart=xs[i][:, None],
                         acc_deaf=xs[i + 1][:, None],
@@ -347,7 +443,7 @@ def _window_scan_impl(
                 xs += (ext,)
             if corrupt:
                 xs += (stale, equiv)
-            if restart:
+            if restart and not placed:
                 xs += (arst, deaf, prst, rc)
             (lease, netc, _), (owners, counts) = jax.lax.scan(
                 body, (tuple(packed), tuple(net), t0), xs
@@ -376,11 +472,16 @@ def _window_scan_impl(
         steps = jnp.zeros(2, jnp.int32)
     else:
         net_p = _pad_net(net, block_n)
-        rst_kw = (
-            dict(acc_restart=arst, acc_deaf=deaf, prop_restart=prst,
-                 prop_rc=rc)
-            if restart else {}
-        )
+        if placed:
+            rst_kw = dict(
+                restart_table=pad_restart_table(rtab, block_n),
+                deaf_q4=deaf_q4,
+            )
+        elif restart:
+            rst_kw = dict(acc_restart=arst, acc_deaf=deaf, prop_restart=prst,
+                          prop_rc=rc)
+        else:
+            rst_kw = {}
         padded, net_p, owners, counts, steps = lease_window_delayed_pallas(
             padded, net_p, t0, attempts_p, releases_p, acc_up, pclk, aclk,
             link, extends=ext_p, stale=stale, equiv=equiv, **rst_kw,
@@ -399,7 +500,7 @@ _window_scan_jit = functools.partial(
     jax.jit,
     static_argnames=(
         "majority", "lease_q4", "round_q4", "guard_q4", "backend", "sync",
-        "block_n", "window", "restart_guard", "skip_stable",
+        "block_n", "window", "restart_guard", "skip_stable", "max_lease_q4",
     ),
 )(_window_scan_impl)
 
@@ -424,6 +525,7 @@ def _margin_scan_impl(
     guard_q4: int,
     rst0=None,
     restart_guard: bool = True,
+    max_lease_q4: int | None = None,
 ):
     """The delayed jnp scan with §4 boundary-proximity margins folded into
     the carry — the body of ``engine.sweep(collect="margins")``. Margins
@@ -490,7 +592,8 @@ def _margin_scan_impl(
             else jnp.asarray(prst, jnp.int32)
         )
         rc, deaf, deaf_rem = _restart_planes(
-            rst0, arst, prst, aclk, lease_q4, restart_guard
+            rst0, arst, prst, aclk,
+            lease_q4 if max_lease_q4 is None else max_lease_q4, restart_guard,
         )
     big = jnp.int32(MARGIN_BIG)
 
@@ -644,7 +747,8 @@ def _guard_pack_budget(
     rate planes' maximum step and any clock offsets already ahead of the
     rate-1 reading are both charged. Restart mode (any restart plane or a
     restart history) charges the ballot carve: the budget shrinks by
-    RESTART_SHIFT bits plus the highest per-proposer restart count."""
+    RESTART_SHIFT bits plus the highest per-proposer restart count (per
+    node, where a placement makes the restart planes node rows)."""
     delay = None if sync else planes.get("delay")
     consulted = (t0, delay, planes.get("prop_rate"), planes.get("acc_rate"),
                  planes.get("acc_restart"), planes.get("prop_restart"))
@@ -685,10 +789,17 @@ def _guard_pack_budget(
     prst = planes.get("prop_restart")
     max_restarts = 0
     if arst is not None or prst is not None or rst0 is not None:
-        rc_end = np.zeros(n_proposers, np.int64)
+        # per proposer, or per node where a placement makes the restart
+        # planes node rows: the highest count any one of them reaches
+        width = n_proposers
+        if prst is not None:
+            width = np.shape(prst)[-1]
+        elif rst0 is not None:
+            width = np.shape(rst0[0])[0]
+        rc_end = np.zeros(width, np.int64)
         if prst is not None:
             rc_end += np.asarray(prst, np.int64).reshape(
-                -1, n_proposers).sum(axis=0)
+                -1, width).sum(axis=0)
         if rst0 is not None:
             rc_end += np.asarray(rst0[0], np.int64)
         # acc-only restart schedules still switch the ballot encoding, so
@@ -700,21 +811,39 @@ def _guard_pack_budget(
     )
 
 
-def strip_default_planes(planes: dict) -> dict:
+def _only_default(plane, default, rows: int = 8) -> bool:
+    """True iff the [T, ...] ``plane`` holds nothing but ``default``. It is
+    read ``rows`` ticks at a time, so a plane in use (a [T, N] extends
+    plane of renewals) is told apart at its first block that is not,
+    without a pass over all of it before the uploads start."""
+    v = np.asarray(plane)
+    return not any(
+        (v[i:i + rows] != default).any() for i in range(0, len(v), rows)
+    )
+
+
+def strip_default_planes(planes: dict, restart_history: bool = False) -> dict:
     """Drop optional fault planes sitting entirely at their registered
     default. All-default corruption/restart/extends planes ARE the honest
     engine, so stripping them host-side keeps the honest replay from
     compiling the fault variants — staticcheck's ``check_honest_strip``
-    pins the resulting dispatch-jaxpr byte-identity. Tracers are never
-    stripped (their values are unknown at trace time)."""
-    return {
+    pins the resulting dispatch-jaxpr byte-identity. A placement goes
+    with its restart planes unless a ``restart_history`` keeps restart
+    mode on: with no restart to place it changes nothing. Tracers are
+    never stripped (their values are unknown at trace time)."""
+    out = {
         k: v for k, v in planes.items()
         if not (
             k in CORRUPTION_PLANES + RESTART_PLANES + EXTEND_PLANES
             and not isinstance(v, jax.core.Tracer)
-            and (np.asarray(v) == PLANES[k].default).all()
+            and _only_default(v, PLANES[k].default)
         )
     }
+    if "placement" in out and not restart_history and not any(
+        k in out for k in RESTART_PLANES
+    ):
+        del out["placement"]
+    return out
 
 
 def lease_window_scan(
@@ -735,6 +864,7 @@ def lease_window_scan(
     block_n: int = 512,
     window: int = 16,
     skip_stable: bool = True,
+    max_lease_q4: int | None = None,
 ) -> tuple[LeaseArrayState, NetPlaneState, jax.Array, jax.Array]:
     """Replay a whole [T]-tick scenario-plane dict in ONE dispatch.
 
@@ -748,26 +878,42 @@ def lease_window_scan(
     accumulated local-clock offsets at ``t0`` (default: the rate-1
     reading ``4·t0`` on every node). ``rst0`` is the (restart-counter [P],
     deaf-until [A]) restart history at ``t0`` (None = fresh; its presence
-    keeps restart mode on even for quiet planes); ``restart_guard=False``
-    disables the post-restart deaf window — the §4 negative control.
+    keeps restart mode on even for quiet planes); a restarted acceptor
+    stays deaf for ``max_lease_q4`` (M, the longest lease any proposer
+    may ask for; default ``lease_q4``) on its own clock, and
+    ``restart_guard=False`` disables that deaf window — the §4 negative
+    control. A ``placement`` plane ([A, N] node ids) makes the restart planes
+    ``[T, K]`` node rows, and ``rst0`` then the nodes' (restart count
+    [K], deaf-until [K] in global quarter-ticks); the clocks must run at
+    the rate-1 step (``scenario.check_placed``).
     ``skip_stable=False`` disables the Pallas quiescence fast path (the
     A/B bench control; results are bit-identical either way).
     Returns (new_state, new_net, owners [T, N], owner_counts [T, N]).
     """
     if guard_q4 is None:
         guard_q4 = lease_q4
-    planes = strip_default_planes(planes)
+    if max_lease_q4 is None:
+        max_lease_q4 = lease_q4
+    planes = strip_default_planes(planes, restart_history=rst0 is not None)
     _guard_pack_budget(
         t0, int(jnp.shape(planes["attempts"])[0]), planes,
         n_proposers=state.n_proposers, lease_q4=lease_q4, sync=sync,
         clk0=clk0, rst0=rst0,
     )
+    rtab = None
+    if "placement" in planes:
+        planes = dict(planes)
+        rtab = placed_restart_table(
+            rst0, planes.pop("acc_restart", None),
+            planes.pop("prop_restart", None), planes.pop("placement"), t0,
+            deaf_q4=max_lease_q4 if restart_guard else 0,
+        )
     return _window_scan_jit(
         state, net, t0, clk0, rst0, planes,
         majority=majority, lease_q4=lease_q4, round_q4=round_q4,
         guard_q4=guard_q4, backend=backend, sync=sync, block_n=block_n,
         window=window, restart_guard=restart_guard,
-        skip_stable=skip_stable,
+        skip_stable=skip_stable, rtab=rtab, max_lease_q4=max_lease_q4,
     )[:4]
 
 
@@ -789,6 +935,7 @@ def _plane_tick(
     sync: bool = False,
     window: int = 16,
     skip_stable: bool = True,
+    max_lease_q4: int | None = None,
 ) -> tuple[LeaseArrayState, NetPlaneState, jax.Array, jax.Array]:
     """:func:`lease_plane_tick`, and the window kernel's grid-step counts
     of the dispatch (``steps``, see ``_window_scan_impl``)."""
@@ -828,7 +975,7 @@ def _plane_tick(
         majority=majority, lease_q4=lease_q4, round_q4=round_q4,
         guard_q4=guard_q4, backend=backend, sync=sync, block_n=block_n,
         window=window, restart_guard=restart_guard,
-        skip_stable=skip_stable,
+        skip_stable=skip_stable, max_lease_q4=max_lease_q4,
     )
     return new_state, new_net, counts[0], steps
 
@@ -855,7 +1002,8 @@ def lease_plane_tick(
 
     Keywords: ``majority``, ``lease_q4`` and ``round_q4`` (required),
     ``guard_q4``, ``clk0``, ``rst0``, ``restart_guard``, ``backend``,
-    ``block_n``, ``sync``, ``window`` and ``skip_stable``, as
+    ``block_n``, ``sync``, ``window``, ``skip_stable`` and
+    ``max_lease_q4``, as
     :func:`lease_window_scan` takes them.
     """
     return _plane_tick(state, net, t, tick, **kw)[:3]

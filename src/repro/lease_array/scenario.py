@@ -13,6 +13,14 @@ leading tick axis:
   drop      [T, P, A]  per-(proposer, acceptor) link loss mask
   prop_rate [T, P]     proposer local-clock step (local quarter-ticks/tick)
   acc_rate  [T, A]     acceptor local-clock step (local quarter-ticks/tick)
+  placement [A, N]     optional: node id of each replica slot of each cell
+
+A ``placement`` (the one plane without a tick axis, and the one a
+scenario may leave out) puts the replicas of each cell on nodes of a
+cluster of ``K`` nodes: slot ``k`` of cell ``n`` — its acceptor ``k`` and
+its proposer ``k`` (``A == P``) — lives on node ``placement[k, n]``, and
+the crash/restart planes become node rows, ``[T, K]``: a restart of node
+``j`` restarts the replica of every cell placed on ``j`` (docs/restarts.md).
 
 ``delay``/``drop`` are *asymmetric link matrices*: every message leg sent
 at tick ``t`` on the link between proposer ``p`` and acceptor ``a`` —
@@ -48,11 +56,12 @@ from typing import Iterable, NamedTuple, Optional
 import jax
 import numpy as np
 
-from .state import DEFAULT_RATE, NO_PROPOSER
+from .state import DEFAULT_RATE, MAX_RESTARTS, NO_PROPOSER
 
 __all__ = [
     "PlaneSpec",
     "PLANES",
+    "LAYOUT_PLANES",
     "CORRUPTION_PLANES",
     "RESTART_PLANES",
     "EXTEND_PLANES",
@@ -63,6 +72,8 @@ __all__ = [
     "TickInputs",
     "make_tick",
     "check_bounds",
+    "check_placed",
+    "PLACED_SHARED",
 ]
 
 
@@ -70,7 +81,7 @@ class PlaneSpec(NamedTuple):
     """Schema of one scenario plane (shapes are per tick, sans the T axis)."""
 
     name: str
-    dims: tuple[str, ...]  # per-tick dims, of {"N", "A", "P"}
+    dims: tuple[str, ...]  # per-tick dims, of {"N", "A", "P", "K"}
     default: int           # fill value when the plane is omitted
     doc: str = ""
     #: alternate per-tick shapes accepted from callers; missing axes are
@@ -81,6 +92,9 @@ class PlaneSpec(NamedTuple):
     proposer_ids: bool = False
     #: entries below this raise at build/validate time (None = unchecked)
     min_value: Optional[int] = None
+    #: the per-tick dims when the scenario carries a placement (the
+    #: restart planes are then node rows)
+    placed_dims: Optional[tuple[str, ...]] = None
 
     @property
     def bounded(self) -> bool:
@@ -91,6 +105,10 @@ class PlaneSpec(NamedTuple):
 
 #: the plane registry — insertion order is the canonical plane order
 PLANES: dict[str, PlaneSpec] = {}
+#: the layout planes: no tick axis (``dims`` are the whole shape), and a
+#: scenario carries one only where it is given — left out, it is its
+#: default (the placement: slot k on node k)
+LAYOUT_PLANES: dict[str, PlaneSpec] = {}
 
 
 def register_plane(
@@ -102,14 +120,19 @@ def register_plane(
     alts: Iterable[Iterable[str]] = (),
     proposer_ids: bool = False,
     min_value: Optional[int] = None,
+    placed_dims: Optional[Iterable[str]] = None,
+    layout: bool = False,
 ) -> PlaneSpec:
-    """Extend the scenario schema with a new named plane."""
+    """Extend the scenario schema with a new named plane (``layout``: a
+    plane without a tick axis that a scenario may leave out,
+    ``LAYOUT_PLANES``)."""
     spec = PlaneSpec(
         name, tuple(dims), int(default), doc,
         tuple(tuple(a) for a in alts), proposer_ids,
         None if min_value is None else int(min_value),
+        None if placed_dims is None else tuple(placed_dims),
     )
-    PLANES[name] = spec
+    (LAYOUT_PLANES if layout else PLANES)[name] = spec
     return spec
 
 
@@ -163,20 +186,30 @@ register_plane(
 register_plane(
     "acc_restart", ("A",), 0,
     "diskless acceptor crash+restart this tick: state blanks, then deaf "
-    "for a maximal lease span on its local clock",
-    min_value=0,
+    "for a maximal lease span on its local clock (with a placement: "
+    "`[K]`, per node)",
+    min_value=0, placed_dims=("K",),
 )
 register_plane(
     "prop_restart", ("P",), 0,
     "proposer crash+restart this tick: abandons its round, drops its owner "
-    "belief, bumps its ballot restart counter",
-    min_value=0,
+    "belief, bumps its ballot restart counter (with a placement: `[K]`, "
+    "per node)",
+    min_value=0, placed_dims=("K",),
 )
 register_plane(
     "extends", ("N",), NO_PROPOSER,
     "proposer id extending its own live lease on each cell this tick "
     "(§6 in-flight re-propose; -1 = none, non-owners are a no-op)",
     proposer_ids=True,
+)
+
+register_plane(
+    "placement", ("A", "N"), 0,
+    "no tick axis; node id in [0, K) of replica slot k of each cell, whose "
+    "acceptor k and proposer k share the node (A = P, ids distinct in a "
+    "cell); absent: slot k is node k",
+    layout=True,
 )
 
 #: the adversarial corruption planes — Byzantine acceptor behaviors the
@@ -196,17 +229,26 @@ RESTART_PLANES = ("acc_restart", "prop_restart")
 #: jaxpr stays byte-identical
 EXTEND_PLANES = ("extends",)
 
+#: the per-slot planes a placement cannot map to nodes yet: with a
+#: placement they must sit at their defaults (the link planes, delay and
+#: drop, stay per slot: the network model every deployment uses)
+PLACED_SHARED = ("acc_up", "acc_rate", "prop_rate")
+
 
 def plane_table_md(planes: Optional[dict[str, PlaneSpec]] = None) -> str:
     """Render the registry as the markdown plane table embedded in
-    docs/scenario_api.md (between the ``plane-table`` markers).
+    docs/scenario_api.md (between the ``plane-table`` markers): the
+    per-tick planes, then the layout planes (default: absent).
 
     The registry is the single source of truth: the table in the docs is
     generated by this function, and the convention lint
     (``repro.analysis.staticcheck.conventions``) fails CI whenever the two
     drift — including when a plane is registered with an empty ``doc``.
     """
-    specs = (PLANES if planes is None else planes).values()
+    specs = (
+        [*PLANES.values(), *LAYOUT_PLANES.values()] if planes is None
+        else planes.values()
+    )
     rows = [
         "| plane | per-tick shape | default | meaning |",
         "|-------|----------------|---------|---------|",
@@ -217,8 +259,11 @@ def plane_table_md(planes: Optional[dict[str, PlaneSpec]] = None) -> str:
             shape += " (or " + " / ".join(
                 "`[" + ", ".join(a) + "]`" for a in spec.alts
             ) + ")"
+        default = (
+            "absent" if spec.name in LAYOUT_PLANES else f"`{spec.default}`"
+        )
         rows.append(
-            f"| `{spec.name}` | {shape} | `{spec.default}` | {spec.doc} |"
+            f"| `{spec.name}` | {shape} | {default} | {spec.doc} |"
         )
     return "\n".join(rows) + "\n"
 
@@ -276,8 +321,89 @@ def check_bounds(
         )
 
 
-def _dim_sizes(n_cells: int, n_acceptors: int, n_proposers: int) -> dict[str, int]:
-    return {"N": int(n_cells), "A": int(n_acceptors), "P": int(n_proposers)}
+def _dim_sizes(
+    n_cells: int, n_acceptors: int, n_proposers: int,
+    n_nodes: Optional[int] = None,
+) -> dict[str, int]:
+    sizes = {"N": int(n_cells), "A": int(n_acceptors), "P": int(n_proposers)}
+    if n_nodes is not None:
+        sizes["K"] = int(n_nodes)
+    return sizes
+
+
+def _plane_dims(spec: PlaneSpec, placed: bool) -> tuple[str, ...]:
+    """A plane's per-tick dims in a scenario with or without a placement."""
+    return spec.placed_dims if placed and spec.placed_dims else spec.dims
+
+
+def check_placement(place: np.ndarray, n_nodes: int, what: str) -> None:
+    """Refuse a ``[A, N]`` placement with a node id outside ``[0, K)`` or
+    two replica slots of one cell on the same node (a node failure would
+    then take two replicas of the cell at once, and the slot's proposer
+    and acceptor would no longer be one node's)."""
+    if not place.size:
+        return
+    lo, hi = int(place.min()), int(place.max())
+    if lo < 0 or hi >= n_nodes:
+        raise ValueError(
+            f"{what} placement has node id {lo if lo < 0 else hi} out of "
+            f"range (the cluster has {n_nodes} nodes, ids 0..{n_nodes - 1})"
+        )
+    A = place.shape[0]
+    for a in range(A):
+        for b in range(a + 1, A):
+            same = np.flatnonzero(place[a] == place[b])
+            if same.size:
+                n = int(same[0])
+                raise ValueError(
+                    f"{what} placement puts replica slots {a} and {b} of "
+                    f"cell {n} on the same node {int(place[a, n])}; the "
+                    f"slots of a cell must be on distinct nodes"
+                )
+
+
+def check_placed(
+    planes: dict, n_nodes: int, n_acceptors: int, n_proposers: int,
+    what: str, *, check_map: bool = True,
+) -> None:
+    """What a scenario with a placement must also satisfy: ``A == P``
+    (slot ``k`` hosts acceptor ``k`` and proposer ``k``), the per-slot
+    planes of ``PLACED_SHARED`` at their defaults (they are not mapped to
+    nodes yet), 0/1 restart node rows with at most ``MAX_RESTARTS``
+    restarts of any node, and (``check_map``) a sound map."""
+    if n_acceptors != n_proposers:
+        raise ValueError(
+            f"{what} with a placement needs as many proposers as acceptors "
+            f"(slot k hosts acceptor k and proposer k); got A={n_acceptors}, "
+            f"P={n_proposers}"
+        )
+    for k in PLACED_SHARED:
+        v = planes.get(k)
+        if v is not None and (np.asarray(v) != PLANES[k].default).any():
+            raise ValueError(
+                f"{what} plane {k!r} departs from its default "
+                f"{PLANES[k].default}; with a placement it would apply per "
+                f"replica slot across every node, so it is refused until it "
+                f"is mapped to nodes (only the restart planes are)"
+            )
+    for k in RESTART_PLANES:
+        v = np.asarray(planes[k])
+        if not v.size:
+            continue
+        if int(v.max()) > 1:
+            raise ValueError(
+                f"{what} plane {k!r} has an entry above 1; with a placement "
+                f"the restart planes are 0/1 node rows"
+            )
+        most = int(v.reshape(-1, v.shape[-1]).sum(axis=0).max())
+        if most > MAX_RESTARTS:
+            raise ValueError(
+                f"{what} plane {k!r} restarts a node {most} times; a "
+                f"placed replay takes at most {MAX_RESTARTS} restarts of "
+                f"each node (split the schedule across run_trace calls)"
+            )
+    if check_map:
+        check_placement(np.asarray(planes["placement"]), n_nodes, what)
 
 
 def _check_plane(
@@ -295,40 +421,47 @@ def _coerce_plane(
     sizes: dict[str, int],
     lead: tuple[int, ...],
     what: str,
+    placed: bool = False,
 ) -> np.ndarray:
-    """Default / validate / broadcast one plane to ``lead + canonical``."""
-    shape = lead + tuple(sizes[d] for d in spec.dims)
+    """Default / validate / broadcast one plane to ``lead + canonical``
+    (a plane without a tick axis takes no ``lead``; with ``placed`` the
+    restart planes are ``[T, K]`` node rows)."""
+    dims = _plane_dims(spec, placed)
+    if spec.name in LAYOUT_PLANES:
+        lead = ()
+    shape = lead + tuple(sizes[d] for d in dims)
     if value is None:
         return np.full(shape, spec.default, np.int32)
     arr = np.asarray(value)
     if arr.dtype == bool:
         arr = arr.astype(np.int32)
     arr = arr.astype(np.int32, copy=False)
-    forms = (spec.dims,) + spec.alts
-    for dims in forms:
-        want = lead + tuple(sizes[d] for d in dims)
+    forms = (dims,) + (spec.alts if dims == spec.dims else ())
+    for form in forms:
+        want = lead + tuple(sizes[d] for d in form)
         if arr.shape == want:
-            if dims != spec.dims:  # expand the alternate form, e.g. [T,A]
-                missing = [d for d in spec.dims if d not in dims]
+            if form != dims:  # expand the alternate form, e.g. [T,A]
+                missing = [d for d in dims if d not in form]
                 for d in missing:
-                    ax = len(lead) + spec.dims.index(d)
+                    ax = len(lead) + dims.index(d)
                     arr = np.expand_dims(arr, ax)
                 arr = np.broadcast_to(arr, shape).copy()
             _check_plane(spec, arr, sizes["P"], what)
             return arr
     accepted = " or ".join(
-        str(lead + tuple(sizes[d] for d in dims)) for dims in forms
+        str(lead + tuple(sizes[d] for d in form)) for form in forms
     )
     raise ValueError(
         f"{what} plane {spec.name!r} has shape {arr.shape}; expected "
-        f"{accepted} (T, N, A, P = ticks, cells, acceptors, proposers)"
+        f"{accepted} (T, N, A, P, K = ticks, cells, acceptors, proposers, "
+        f"nodes)"
     )
 
 
 def _raise_unknown(bad):
     raise ValueError(
         f"unknown scenario plane(s) {sorted(bad)}; registered planes: "
-        f"{sorted(PLANES)} (extend with register_plane)"
+        f"{sorted([*PLANES, *LAYOUT_PLANES])} (extend with register_plane)"
     )
 
 
@@ -339,9 +472,11 @@ class _PlaneBundle:
     _lead_ndim = 0  # leading axes before the per-tick dims
 
     def __init__(self, planes: dict) -> None:
-        if bad := set(planes) - set(PLANES):
+        if bad := set(planes) - set(PLANES) - set(LAYOUT_PLANES):
             _raise_unknown(bad)
-        self.planes = {k: planes[k] for k in PLANES if k in planes}
+        self.planes = {
+            k: planes[k] for k in (*PLANES, *LAYOUT_PLANES) if k in planes
+        }
 
     def __getattr__(self, name: str):
         if name == "planes":  # unset slot (e.g. during unpickling probes)
@@ -365,6 +500,17 @@ class _PlaneBundle:
     @property
     def n_proposers(self) -> int:
         return self._dim("delay", 0)
+
+    @property
+    def placed(self) -> bool:
+        """True iff the bundle carries a placement (its restart planes
+        are then node rows)."""
+        return "placement" in self.planes
+
+    @property
+    def n_nodes(self) -> Optional[int]:
+        """K, the cluster's node count, with a placement (else None)."""
+        return self._dim("acc_restart", 0) if self.placed else None
 
     @property
     def delayed(self) -> bool:
@@ -422,17 +568,23 @@ class _PlaneBundle:
         the scanner. The planes named in ``skip_bounds`` have their shapes
         checked and their entries left unread: their caller checks those
         bounds itself (``engine.run_trace`` reads them on the device after
-        the upload) through the same ``check_bounds``."""
-        sizes = _dim_sizes(n_cells, n_acceptors, n_proposers)
+        the upload) through the same ``check_bounds``; ``"placement"``
+        there leaves ``check_placed`` to the caller likewise."""
+        placed = self.placed
+        sizes = _dim_sizes(n_cells, n_acceptors, n_proposers, self.n_nodes)
         lead: tuple[int, ...] = ()
         if self._lead_ndim:
             lead = (int(self.planes["attempts"].shape[0]),)
         what = type(self).__name__
-        for name, spec in PLANES.items():
+        for name, spec in (*PLANES.items(), *LAYOUT_PLANES.items()):
             if name not in self.planes:
+                if name in LAYOUT_PLANES:
+                    continue
                 raise ValueError(f"{what} is missing plane {name!r}")
             arr = np.asarray(self.planes[name])
-            want = lead + tuple(sizes[d] for d in spec.dims)
+            want = (() if name in LAYOUT_PLANES else lead) + tuple(
+                sizes[d] for d in _plane_dims(spec, placed)
+            )
             if arr.shape != want:
                 raise ValueError(
                     f"{what} plane {name!r} has shape {arr.shape}; "
@@ -440,6 +592,10 @@ class _PlaneBundle:
                 )
             if name not in skip_bounds:
                 _check_plane(spec, arr, sizes["P"], what)
+        if placed and "placement" not in skip_bounds:
+            check_placed(
+                self.planes, sizes["K"], n_acceptors, n_proposers, what
+            )
 
     def __repr__(self) -> str:
         inner = ", ".join(
@@ -474,6 +630,11 @@ def make_tick(
     Omitted planes get their registered defaults; ``delay``/``drop`` accept
     the symmetric per-acceptor ``[A]`` form and broadcast it over P.
     """
+    if planes.get("placement") is not None:
+        raise ValueError(
+            "a tick takes no placement: a placed deployment is replayed "
+            "whole, by run_trace"
+        )
     if bad := set(planes) - set(PLANES):
         _raise_unknown(bad)
     sizes = _dim_sizes(n_cells, n_acceptors, n_proposers)
@@ -503,31 +664,46 @@ class Scenario(_PlaneBundle):
         n_cells: int,
         n_acceptors: int,
         n_proposers: int,
+        n_nodes: Optional[int] = None,
         **planes,
     ) -> "Scenario":
         """Default, validate and broadcast every registered plane.
 
-        ``n_ticks`` may be omitted when at least one plane is given (it is
-        inferred from the first one). Unknown plane names are rejected with
-        the list of registered planes.
+        ``n_ticks`` may be omitted when at least one plane with a tick
+        axis is given (it is inferred from the first one). Unknown plane
+        names are rejected with the list of registered planes. A
+        ``placement`` needs ``n_nodes`` (K); its restart planes are then
+        ``[T, K]`` node rows (``check_placed``).
         """
-        if bad := {k for k in planes if k not in PLANES}:
+        if bad := set(planes) - set(PLANES) - set(LAYOUT_PLANES):
             _raise_unknown(bad)
+        placed = planes.get("placement") is not None
+        if placed != (n_nodes is not None):
+            raise ValueError(
+                "a placement needs n_nodes (K, the cluster's node count), "
+                "and n_nodes needs a placement"
+            )
         if n_ticks is None:
-            for v in planes.values():
-                if v is not None:
+            for k, v in planes.items():
+                if v is not None and k in PLANES:
                     n_ticks = int(np.asarray(v).shape[0])
                     break
             else:
                 raise ValueError(
                     "n_ticks is required when no plane is provided"
                 )
-        sizes = _dim_sizes(n_cells, n_acceptors, n_proposers)
+        sizes = _dim_sizes(n_cells, n_acceptors, n_proposers, n_nodes)
         lead = (int(n_ticks),)
-        return cls({
-            name: _coerce_plane(spec, planes.get(name), sizes, lead, "scenario")
-            for name, spec in PLANES.items()
-        })
+        out = {
+            name: _coerce_plane(
+                spec, planes.get(name), sizes, lead, "scenario", placed
+            )
+            for name, spec in (*PLANES.items(), *LAYOUT_PLANES.items())
+            if name in PLANES or planes.get(name) is not None
+        }
+        if placed:
+            check_placed(out, n_nodes, n_acceptors, n_proposers, "scenario")
+        return cls(out)
 
     # ------------------------------------------------------------- queries
     @property
@@ -537,15 +713,34 @@ class Scenario(_PlaneBundle):
     # -------------------------------------------------------- composition
     def __getitem__(self, key):
         if isinstance(key, slice):
-            return Scenario({k: v[key] for k, v in self.planes.items()})
+            return Scenario({
+                k: v if k in LAYOUT_PLANES else v[key]
+                for k, v in self.planes.items()
+            })
+        if self.placed:
+            raise ValueError(
+                "a placed scenario has no single-tick form: its restart "
+                "planes are node rows, replayed whole by run_trace"
+            )
         return TickInputs({k: v[key] for k, v in self.planes.items()})
 
     def concat(self, *others: "Scenario") -> "Scenario":
-        """Concatenate scenarios along the tick axis (same geometry)."""
+        """Concatenate scenarios along the tick axis (same geometry; a
+        placement, which has no tick axis, must be the same in all)."""
         for o in others:
-            for name in PLANES:
+            if set(o.planes) != set(self.planes):
+                raise ValueError(
+                    "cannot concat: the scenarios carry different planes "
+                    f"({sorted(set(o.planes) ^ set(self.planes))})"
+                )
+            for name in self.planes:
                 a, b = self.planes[name], o.planes[name]
-                if a.shape[1:] != b.shape[1:]:
+                if name in LAYOUT_PLANES:
+                    if not np.array_equal(np.asarray(a), np.asarray(b)):
+                        raise ValueError(
+                            f"cannot concat: plane {name!r} differs"
+                        )
+                elif a.shape[1:] != b.shape[1:]:
                     raise ValueError(
                         f"cannot concat: plane {name!r} per-tick shapes "
                         f"differ ({a.shape[1:]} vs {b.shape[1:]})"
@@ -554,7 +749,7 @@ class Scenario(_PlaneBundle):
             k: np.concatenate(
                 [np.asarray(self.planes[k])]
                 + [np.asarray(o.planes[k]) for o in others], axis=0,
-            )
+            ) if k in PLANES else self.planes[k]
             for k in self.planes
         })
 
@@ -570,7 +765,12 @@ class Scenario(_PlaneBundle):
             raise ValueError("Scenario.stack needs at least one scenario")
         first = scenarios[0]
         for i, sc in enumerate(scenarios[1:], 1):
-            for name in PLANES:
+            if set(sc.planes) != set(first.planes):
+                raise ValueError(
+                    f"cannot stack: scenario {i} carries different planes "
+                    f"than scenario 0"
+                )
+            for name in first.planes:
                 a = np.asarray(first.planes[name])
                 b = np.asarray(sc.planes[name])
                 if a.shape != b.shape:

@@ -574,7 +574,10 @@ def _pin_network_to_trace(
     net.set_drop_policy(drop_policy)
 
 
-def replay_event_sim(trace: Trace, *, strict_monitor: bool = True) -> np.ndarray:
+def replay_event_sim(
+    trace: Trace, *, strict_monitor: bool = True,
+    max_lease_ticks: Optional[int] = None,
+) -> np.ndarray:
     """Owners [T, N] by replaying the trace through the event-driven core/
     engine (dedicated acceptor ensemble + detached proposer fleet, message
     timing pinned to the trace's delay/drop planes). The trace is the only
@@ -591,7 +594,8 @@ def replay_event_sim(trace: Trace, *, strict_monitor: bool = True) -> np.ndarray
     array's floor-quantized ``guarded_lease_q4`` local quarters — the
     cross-engine discount regression test asserts the two arithmetics
     agree to the quarter-tick, making this a timing pin, not a semantic
-    change."""
+    change. ``max_lease_ticks`` is M, a restarted acceptor's deaf span
+    (default: the trace's lease), as ``LeaseArrayEngine`` takes it."""
     for name, rates in (("prop_rate", trace.prop_rate),
                         ("acc_rate", trace.acc_rate)):
         if rates is not None and np.asarray(rates).size:
@@ -644,8 +648,11 @@ def replay_event_sim(trace: Trace, *, strict_monitor: bool = True) -> np.ndarray
     # 1/MAX_REFEREE_RATE > TICK_EPS, landing the rejoin strictly after
     # the tick's sampling, i.e. the NEXT tick processes requests, exactly
     # the array's ceil(lease_q4/r) deaf span). Proposers have no deaf
-    # rule: they rejoin instantly (handled in the loop below).
-    lease_q4 = lease_quarters(trace.lease_ticks)
+    # rule: they rejoin instantly (handled in the loop below). The span is
+    # M, ``max_lease_ticks`` (default: the lease), as the engine's.
+    lease_q4 = lease_quarters(
+        trace.lease_ticks if max_lease_ticks is None else max_lease_ticks
+    )
     for a, node in enumerate(acc_nodes):
         r = DEFAULT_RATE if trace.acc_rate is None else int(trace.acc_rate[a])
         # lease_timespan is dead weight on a pure-acceptor node (spans ride

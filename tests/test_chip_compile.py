@@ -80,6 +80,24 @@ def _delayed(sds, *, extend_restart=False, skip_stable=True):
     return fn, (args, streams)
 
 
+def _placed(sds):
+    """A placement's restart table in place of the restart columns: three
+    replicas, slot k's acceptor and proposer on one node (A == P)."""
+    args, streams = delayed_kernel_args(
+        3, N, 3, T, extend=True, restart=True, placed=True,
+        sharding=sds().sharding,
+    )
+
+    def fn(args, streams):
+        return lease_window_delayed_pallas(
+            *args, **streams, majority=2, lease_q4=113, round_q4=20,
+            n_proposers=3, block_n=BLOCK_N, window=WINDOW, interpret=False,
+            deaf_q4=113,
+        )
+
+    return fn, (args, streams)
+
+
 VARIANTS = {
     "sync": _sync,
     "delayed_honest": _delayed,
@@ -87,6 +105,7 @@ VARIANTS = {
         _delayed, extend_restart=True
     ),
     "delayed_no_skip_stable": functools.partial(_delayed, skip_stable=False),
+    "delayed_placed": _placed,
 }
 
 

@@ -259,7 +259,7 @@ def test_only_default_finds_a_value_in_any_block(tick):
     """run_trace's default-plane test reads the plane block by block; a
     value off the default in any tick, the last block's included, is
     found."""
-    from repro.lease_array.engine import _only_default
+    from repro.lease_array.ops import _only_default
 
     plane = np.full((20, 64), NO_PROPOSER, np.int32)
     if tick is not None:

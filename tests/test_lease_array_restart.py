@@ -92,6 +92,28 @@ def test_crash_restart_differential_vs_referee(seed):
     assert int(np.max(np.asarray(cn))) <= 1
 
 
+@pytest.mark.parametrize("seed", [0, 3])  # 3: drifted, asymmetric links
+def test_a_longer_m_matches_the_referee_by_trace_and_by_step(seed):
+    """M longer than the lease (the engine's ``max_lease_ticks``): a
+    restarted acceptor stays deaf for M on its own clock in the fused
+    replay, tick by tick through ``step``, and in the referee alike; and
+    M is honored, for the replay differs from one at the default span."""
+    tr = random_trace(
+        seed, drift_eps=0.25 if seed % 2 else 0.0,
+        asymmetric=bool(seed % 2), **CHAOS,
+    )
+    m = tr.lease_ticks + 8
+    ref = replay_event_sim(tr, max_lease_ticks=m)
+    sc = tr.scenario()
+    ow, cn = _engine(tr, max_lease_ticks=m).run_trace(sc)
+    assert np.array_equal(ref, np.asarray(ow))
+    assert int(np.max(np.asarray(cn))) <= 1
+    stepped = _engine(tr, max_lease_ticks=m)
+    rows = [stepped.step(sc[t]) for t in range(tr.n_ticks)]
+    assert np.array_equal(np.stack(rows), np.asarray(ow))
+    assert not np.array_equal(np.asarray(replay_array(tr)[0]), ref)
+
+
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_restart_trace_backends_bit_identical(backend):
     """The restart-mode dispatch (blanking, deaf gating, counter-carved
