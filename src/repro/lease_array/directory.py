@@ -141,7 +141,6 @@ class LeaseArrayDirectory:
             ticks_left = self.engine.ticks_left()
 
             with span("lease.dir.shed"):
-                by_slot = {w.slot: w for w in self.workers.values()}
                 counts = np.bincount(
                     owners[owners >= 0], minlength=self.engine.n_proposers
                 )
@@ -161,23 +160,29 @@ class LeaseArrayDirectory:
 
             # owners inside the renew margin extend in-flight (§6: the
             # extends plane re-proposes under the live belief;
-            # stalled/draining don't)
+            # stalled/draining don't), as one mask over every shard: on a
+            # storm tick every owned shard is due at once, and a per-cell
+            # Python loop costs more than the batched step itself
             with span("lease.dir.renew") as renew_span:
-                candidates = np.flatnonzero(
+                due = (
                     (owners >= 0)
                     & (ticks_left <= self.renew_margin)
                     & (self._cooldown == 0)
                 )
-                for cell in candidates:
-                    w = by_slot.get(int(owners[cell]))
-                    if w is not None and not w.stalled and not w.draining:
-                        if release[cell] != w.slot:  # not shedding this one
-                            extend[cell] = w.slot
-                            self._cooldown[cell] = self._round_trip
+                renews = np.zeros(self.engine.n_proposers, bool)
+                for w in self.workers.values():
+                    renews[w.slot] = not (w.stalled or w.draining)
+                # not shedding this one: a release names the cell's owner
+                renew = (
+                    due & renews[np.maximum(owners, 0)] & (release != owners)
+                )
+                np.copyto(extend, owners, where=renew)
+                self._cooldown[renew] = self._round_trip
                 if tracing:
-                    n_extends = int(np.count_nonzero(extend >= 0))
+                    n_extends = int(np.count_nonzero(renew))
                     renew_span.set_metadata(
-                        candidates=len(candidates), extends=n_extends
+                        candidates=int(np.count_nonzero(due)),
+                        extends=n_extends,
                     )
 
             # spread unowned cells over deficit workers round-robin

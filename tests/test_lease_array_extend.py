@@ -328,3 +328,141 @@ def test_directory_rejects_unservable_renewal_geometry():
     with pytest.raises(ValueError, match="below the worst-case"):
         LeaseArrayDirectory(8, n_acceptors=3, lease_ticks=24,
                             max_delay_ticks=4, renew_margin=12)
+
+
+# ------------------------------------------ the directory's §6 renewal step
+
+class _SpanLog:
+    """Stands in for the directory's span: every span reads as traced and
+    keeps its metadata, in the order the spans close."""
+
+    def __init__(self):
+        self.closed = []
+
+    def is_enabled(self):
+        return True
+
+    def __call__(self, name):
+        log = self
+
+        class _Span:
+            meta = {}
+
+            def __enter__(self):
+                self.meta = {}
+                return self
+
+            def __exit__(self, *exc):
+                log.closed.append((name, self.meta))
+
+            def set_metadata(self, **kw):
+                self.meta.update(kw)
+
+        return _Span()
+
+
+def _renewal_rule(d, owners, ticks_left, cooldown, release):
+    """The §6 renewal step cell by cell, over the state a tick starts
+    from: each owned shard inside the renew margin and off cooldown is a
+    candidate; its owner extends it if that owner is a registered worker,
+    neither stalled nor draining, and not shedding the shard this tick.
+    Returns the extends plane, the cooldown after it, and the candidate
+    count."""
+    extend = np.full(d.n_shards, NA, np.int32)
+    cooldown = cooldown.copy()
+    by_slot = {w.slot: w for w in d.workers.values()}
+    candidates = np.flatnonzero(
+        (owners >= 0) & (ticks_left <= d.renew_margin) & (cooldown == 0)
+    )
+    for cell in candidates:
+        w = by_slot.get(int(owners[cell]))
+        if w is not None and not w.stalled and not w.draining:
+            if release[cell] != w.slot:
+                extend[cell] = w.slot
+                cooldown[cell] = d._round_trip
+    return extend, cooldown, len(candidates)
+
+
+@pytest.mark.parametrize("max_delay_ticks,lease_ticks",
+                         [(0, 6), (1, 8), (2, 12)])
+def test_directory_renewals_match_the_per_cell_rule(monkeypatch,
+                                                     max_delay_ticks,
+                                                     lease_ticks):
+    """Through an acquire storm, a stall, a target lowered a few shards a
+    tick (so shed shards fall inside the renew margin) and a drain, every
+    tick's extends plane, the cooldown after it and the renewal span's
+    ``candidates`` are what the per-cell rule gives over the same owners,
+    ticks left, cooldown and releases."""
+    from repro.lease_array import directory as directory_mod
+    from repro.lease_array.directory import LeaseArrayDirectory
+
+    issued = []
+    make_tick = directory_mod.make_tick
+
+    def spy(**planes):
+        issued.append(planes)
+        return make_tick(**planes)
+
+    spans = _SpanLog()
+    monkeypatch.setattr(directory_mod, "make_tick", spy)
+    monkeypatch.setattr(directory_mod, "span", spans)
+    d = LeaseArrayDirectory(128, n_acceptors=3, lease_ticks=lease_ticks,
+                            max_workers=5, max_delay_ticks=max_delay_ticks,
+                            backend="jnp")
+    for w in range(4):
+        d.add_worker(w, 32)
+    # proposer 4, the plane's slot with no worker, takes 8 shards before
+    # the directory's first tick: no worker renews them, so they lapse
+    attempts = np.full(128, NA, np.int32)
+    attempts[:8] = 4
+    links = ({"delay": np.full(3, max_delay_ticks, np.int32)}
+             if max_delay_ticks else {})
+    for k in range(d._round_trip):
+        d._owners = d.engine.step(make_tick(
+            n_cells=128, n_acceptors=3, n_proposers=5,
+            attempts=attempts if k == 0 else None, **links,
+        )).astype(np.int32)
+    assert (d._owners[:8] == 4).all()
+    lefts = []
+    ticks_left = d.engine.ticks_left
+
+    def kept_ticks_left():
+        lefts.append(ticks_left())
+        return lefts[-1]
+
+    d.engine.ticks_left = kept_ticks_left
+
+    events = {2 * lease_ticks: lambda: d.stall(1),
+              4 * lease_ticks + 2: lambda: d.drain(2)}
+    for k in range(lease_ticks):  # 32 -> 20 shards, a few a tick
+        events[3 * lease_ticks + k] = (
+            lambda k=k: d.set_target(0, 32 - 2 * (k + 1)))
+    shed_due = stalled_due = unheld_due = extended = 0
+    for t in range(5 * lease_ticks + 4):
+        if t in events:
+            events[t]()
+        owners, cooldown = d._owners.copy(), d._cooldown.copy()
+        stalled = {w.slot for w in d.workers.values() if w.stalled}
+        d.tick(1)
+        planes = issued[-1]
+        release = planes["releases"]
+        extend, cool, n_candidates = _renewal_rule(
+            d, owners, lefts[-1], np.maximum(cooldown - 1, 0), release)
+        assert np.array_equal(planes["extends"], extend), f"tick {t}"
+        # the assign step then cools the cells it attempts
+        cool[planes["attempts"] >= 0] = d._round_trip
+        assert np.array_equal(d._cooldown, cool), f"tick {t}"
+        renews = [m for n, m in spans.closed if n == "lease.dir.renew"]
+        assert len(renews) == t + 1
+        renew_meta = renews[-1]
+        assert renew_meta == {"candidates": n_candidates,
+                              "extends": int(np.count_nonzero(extend >= 0))}
+        due = ((owners >= 0) & (lefts[-1] <= d.renew_margin)
+               & (np.maximum(cooldown - 1, 0) == 0))
+        shed_due += int(np.count_nonzero(due & (release == owners)))
+        stalled_due += int(np.count_nonzero(
+            due & np.isin(owners, list(stalled))))
+        unheld_due += int(np.count_nonzero(due & (owners == 4)))
+        extended += int(np.count_nonzero(extend >= 0))
+    # the run reached every branch of the rule
+    assert shed_due and stalled_due and unheld_due and extended
